@@ -89,7 +89,6 @@ class ModelConfig:
     remat: bool = True                # checkpoint each scanned period
     remat_policy: str = "full"        # full | save_mixer (keep attention/scan
                                       # outputs; don't recompute them in bwd)
-    use_pallas: bool = False          # TPU kernels (CPU falls back to refs)
     # beyond-paper perf knobs (EXPERIMENTS.md SSPerf):
     seq_shard_attn: bool = False      # sequence-parallel attention: shard S over
                                       # "model" when heads % model_axis != 0
@@ -495,8 +494,6 @@ class FedConfig:
                                       # hash/sign stream per run keyed from
                                       # fold_in(seed, "wire_sketch")). Must
                                       # be >= 1
-    use_pallas: bool = False          # aggregate via the fedagg Pallas TPU
-                                      # kernel (CPU keeps the jnp lowering)
     fused_agg: bool = True            # flatten the whole client-stacked pytree
                                       # to [C, M_total]: ONE fedagg call per
                                       # round instead of one per leaf

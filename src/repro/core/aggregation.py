@@ -4,7 +4,7 @@
 
 over client-stacked parameter pytrees. The default ``fused`` path flattens
 the WHOLE pytree into one [C, M_total] buffer and invokes the ``fedagg``
-kernel (Pallas on TPU, its jnp lowering on CPU) ONCE per round instead of
+kernel (Pallas on TPU, its jnp lowering elsewhere) ONCE per round instead of
 once per leaf — one kernel launch, one contraction, and under pjit with the
 client axis sharded over (pod, data) exactly one all-reduce: FedALIGN's
 entire server-side communication. Accumulation is f32 regardless of leaf
@@ -89,7 +89,7 @@ def flatten_stacked(client_params, dtype=jnp.float32):
         [leaf.reshape(C, -1).astype(dtype) for leaf in leaves], axis=1)
 
 
-def aggregate_clients(client_params, weights, gates, *, use_pallas=False,
+def aggregate_clients(client_params, weights, gates, *, use_pallas=None,
                       fused=True, interpret=False, aggregator="mean",
                       fed=None, key=None, wire_codec="identity",
                       ef_accum=None):
@@ -774,7 +774,6 @@ def aggregate_delta(global_params, client_params, weights, gates, *,
     codec_name = resolve_wire_codec(getattr(fed, "wire_codec", "identity"))
     if codec_name != "identity":
         return aggregate_clients(deltas, weights, gates,
-                                 use_pallas=fed.use_pallas,
                                  fused=fed.fused_agg, interpret=interpret,
                                  aggregator=getattr(fed, "aggregator", "mean"),
                                  fed=fed, key=key, wire_codec=codec_name,
@@ -784,7 +783,6 @@ def aggregate_delta(global_params, client_params, weights, gates, *,
             "ef_accum given but fed.wire_codec='identity': the lossless "
             "wire has no compression residual to accumulate")
     return aggregate_clients(deltas, weights, gates,
-                             use_pallas=fed.use_pallas,
                              fused=fed.fused_agg, interpret=interpret,
                              aggregator=getattr(fed, "aggregator", "mean"),
                              fed=fed, key=key)

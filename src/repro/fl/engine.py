@@ -74,8 +74,8 @@ one pytree.
 
 Aggregation routes through `core.aggregation.aggregate_delta`: the whole
 client-stacked delta pytree fuses into one [C, M_total] buffer and hits
-the `fedagg` kernel once per round (`FedConfig.use_pallas` selects the
-Pallas TPU kernel; `agg_dtype` casts client deltas on the wire). The
+the `fedagg` kernel once per round (the compiled Pallas kernel on TPU,
+its jnp lowering elsewhere; `agg_dtype` casts client deltas on the wire). The
 aggregated delta then feeds the decorator-registered ServerOptimizer
 (`FedConfig.server_opt`: sgd | momentum | adam | yogi) via
 `apply_server_opt` — immediately in the synchronous backends, or

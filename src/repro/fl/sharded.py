@@ -1,6 +1,6 @@
 """Pod-scale FedALIGN: the communication round as a single pjit program.
 
-Two execution modes, chosen by model size (DESIGN.md §3):
+Two execution modes, chosen by device memory (``choose_round``):
 
 * **spatial** — clients ARE the (pod, data) mesh shards. Client-stacked
   params [C, ...] are vmapped through E local SGD steps in parallel; the
@@ -59,6 +59,8 @@ spatially and the temporal round refuses rather than silently diverge.
 """
 from __future__ import annotations
 
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +72,8 @@ from repro.core.aggregation import (aggregator_key, apply_server_opt,
                                     resolve_wire_codec)
 from repro.core.alignment import epsilon_at
 from repro.fl import engine
+from repro.kernels import ops as kops
+from repro.sharding.specs import dp_axes, tp_axes
 from repro.utils import fold_in_name, tree_axpy, tree_sub
 
 FSDP_ARCHS = {"jamba-1.5-large-398b", "llava-next-34b"}
@@ -77,6 +81,78 @@ FSDP_ARCHS = {"jamba-1.5-large-398b", "llava-next-34b"}
 
 def needs_fsdp(cfg) -> bool:
     return cfg.name in FSDP_ARCHS
+
+
+def _fits(compiled, bytes_limit) -> bool:
+    """Whether a compiled program's bytes (arguments, temporaries, and
+    outputs not aliased to a donated argument) fit ``bytes_limit``."""
+    mem = compiled.memory_analysis()
+    if bytes_limit is None or mem is None:
+        return True
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    return need <= bytes_limit
+
+
+def choose_round(cfg, compile_round, bytes_limit):
+    """Pick the round from memory by compiling it: the spatial round where
+    its program fits the device's ``bytes_limit``, else the temporal
+    (client-streaming) round. A spatial compile that runs out of device
+    memory counts as not fitting. The archs ``needs_fsdp`` names go
+    straight to the temporal round; no reported limit (CPU) keeps the
+    spatial one. ``compile_round(fsdp)`` returns the compiled round;
+    returns ``(fsdp, compiled)``."""
+    if not needs_fsdp(cfg):
+        try:
+            compiled = compile_round(False)
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+        else:
+            if _fits(compiled, bytes_limit):
+                return False, compiled
+    return True, compile_round(True)
+
+
+def _context_mesh():
+    """The multi-device mesh the round is traced under (``jax.set_mesh``),
+    or None on one device."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or mesh.size == 1 else mesh
+
+
+def _kernels_per_shard(round_step):
+    """Trace ``round_step`` with its Pallas kernels placed per shard of the
+    context mesh (XLA cannot partition a Mosaic kernel): batch dims over
+    the data-parallel axes, heads over the model axes. Client-vmapped
+    bodies narrow this (``_client_vmap``)."""
+    @functools.wraps(round_step)
+    def step(*args, **kwargs):
+        mesh = _context_mesh()
+        axes = ((), ()) if mesh is None else (dp_axes(mesh), tp_axes(mesh))
+        with kops.kernels_per_shard(mesh, *axes):
+            return round_step(*args, **kwargs)
+    return step
+
+
+def _client_vmap(fn):
+    """``jax.vmap(fn)`` over the leading client axis. On a multi-device
+    mesh whose data-parallel axes divide the client count, the client
+    axis maps over them (``spmd_axis_name``): each shard trains its own
+    clients, and the Pallas kernels inside stay client-local (their own
+    batch dims whole on the shard), so no activation crosses chips."""
+    def mapped(*args):
+        mesh = _context_mesh()
+        dp = () if mesh is None else dp_axes(mesh)
+        n = jax.tree.leaves(args)[0].shape[0]
+        if not dp or n % math.prod(mesh.shape[a] for a in dp):
+            return jax.vmap(fn)(*args)
+
+        def local(*a):
+            with kops.kernels_per_shard(mesh, (), tp_axes(mesh)):
+                return fn(*a)
+        return jax.vmap(local, spmd_axis_name=dp)(*args)
+    return mapped
 
 
 def _train_steps(model, params, batch, lr, n_steps):
@@ -323,7 +399,7 @@ def make_spatial_round(model, fed, num_clients: int):
 
         if use_cohort:
             # eval -> gates -> gather-train: only K cohort slots pay E steps
-            local_losses = jax.vmap(
+            local_losses = _client_vmap(
                 lambda cb: model.loss_fn(params, cb)[0])(client_batch)
             util_ema = engine.utility_update(fed, state.util_ema,
                                              local_losses, server_loss)
@@ -335,7 +411,7 @@ def make_spatial_round(model, fed, num_clients: int):
                 sel_gates, local_losses, server_loss, pm,
                 min(fed.max_cohort, C), backlog=state.backlog,
                 backlog_boost=float(fed.backlog_boost))
-            cohort_params = jax.vmap(
+            cohort_params = _client_vmap(
                 lambda cb: _train_steps(model, params, cb, lr, E))(
                 jax.tree.map(lambda a: a[idx], client_batch))
             if ctf is not None:
@@ -362,7 +438,7 @@ def make_spatial_round(model, fed, num_clients: int):
                 agg_delta = engine.server_delta(fed, params, cohort_params,
                                                 agg_w, agg_g, key=akey)
         else:
-            client_params, local_losses = jax.vmap(
+            client_params, local_losses = _client_vmap(
                 lambda cb: _local_steps(model, params, cb, lr, E))(client_batch)
             util_ema = engine.utility_update(fed, state.util_ema,
                                              local_losses, server_loss)
@@ -423,7 +499,7 @@ def make_spatial_round(model, fed, num_clients: int):
         stats = _failure_stats(fed, stats, lost, new_state.nonfinite_skips)
         return new_state, stats
 
-    return _pool_wrap(fed, round_step)
+    return _kernels_per_shard(_pool_wrap(fed, round_step))
 
 
 def make_temporal_round(model, fed, cohort: int):
@@ -617,7 +693,7 @@ def make_temporal_round(model, fed, cohort: int):
         stats = _failure_stats(fed, stats, lost, new_state.nonfinite_skips)
         return new_state, stats
 
-    return _pool_wrap(fed, round_step)
+    return _kernels_per_shard(_pool_wrap(fed, round_step))
 
 
 def make_round_step(model, fed, num_clients: int, *, fsdp: bool):

@@ -6,32 +6,32 @@ updates (flattened to [C, M]), data fractions p_k and inclusion gates I_k,
     out[m] = sum_k p_k I_k u[k, m] / sum_k p_k I_k
 
 The parameter axis M is tiled in ``block_m`` columns; each grid cell loads a
-[C, block_m] update slab into VMEM plus the tiny weight/gate vectors, and
-emits one [block_m] output row. The mean reduction over clients is a
-[1,C]x[C,bm] MXU contraction. Memory-bound (arithmetic intensity ~= 1
-FLOP/byte), so block_m is sized for DMA efficiency (multiples of 512 lanes).
+[C, block_m] update slab into VMEM plus the per-client [C, 1] weight/gate
+columns, and emits one [1, block_m] output row. Every operand is 2-D
+(Mosaic lays out only 2-D tiles), and the client reduction is a sublane
+sum on the VPU. Memory-bound (arithmetic intensity ~= 1 FLOP/byte), so
+block_m is a multiple of 128 lanes sized for DMA efficiency.
 
 Robust / private variants are FUSED INTO THE SAME GRID CELL — the [C, bm]
-slab is already in VMEM, so a coordinate-wise sort/select (``trimmed_mean``,
+slab is already in VMEM, so a coordinate-wise select (``trimmed_mean``,
 ``median``), a per-client clip scale + noise add (``dp``), or a gate rewrite
 (``cosine_filter``, handled upstream as a gate pre-pass) costs ~0 extra HBM
 traffic versus a second pass over the parameters:
 
-- ``trimmed_mean`` / ``median`` sort each column over the client axis with a
-  bitonic compare/exchange network (C padded to a power of two; excluded
-  clients keyed to +inf so the n included values occupy positions [0, n))
-  and reduce the surviving order statistics. Both are UNWEIGHTED over the
-  included clients (the Byzantine-robust convention of coordinate-wise
-  trimmed mean / median, Yin et al., arXiv:1803.01498) — p_k weighting
-  would let one heavy client dominate the order statistics it is supposed
-  to be protected from.
+- ``trimmed_mean`` / ``median`` rank each column over the client axis
+  (``order_stat_reduce``; excluded clients keyed to +inf so the n included
+  values take ranks [0, n)) and reduce the surviving order statistics.
+  Both are UNWEIGHTED over the included clients (the Byzantine-robust
+  convention of coordinate-wise trimmed mean / median, Yin et al.,
+  arXiv:1803.01498) — p_k weighting would let one heavy client dominate
+  the order statistics it is supposed to be protected from.
 - ``dp`` applies a per-client multiplicative clip scale (computed upstream
-  from whole-model L2 norms) inside the weighted contraction and adds
+  from whole-model L2 norms) inside the weighted sum and adds
   pre-generated Gaussian noise scaled by ``noise_scale / den`` — DP-FedAvg
   (McMahan et al., arXiv:1710.06963) on the renormalized gated mean. The
   noise vector is generated OUTSIDE the kernel with jax.random so the
-  Pallas and jnp lowerings are bit-comparable (the in-kernel TPU PRNG
-  would diverge from the CPU path).
+  Pallas and jnp lowerings are comparable (the in-kernel TPU PRNG would
+  diverge from the CPU path).
 
 Every variant returns an EXACT zero vector when no client is included
 (zero inclusion mass) — the old 0/1e-30 guard is kept only as a
@@ -39,10 +39,11 @@ divide-safety net, never observed. Gated-out rows are masked before the
 reduction so a non-finite update from an excluded client cannot leak
 through 0 * NaN.
 
-TPU caveat (ROADMAP): CI exercises interpret mode on CPU; the sort-network
-variants lower through jnp primitives (take_along_axis / min / max / where)
-that Mosaic supports, but like every kernel here they are unvalidated on
-real hardware.
+On TPU every aggregator compiles with the identity and int8 wire codecs.
+The topk and sketch decoders index into a whole [C, k] / [C, dim]
+operand per grid cell (a gather Mosaic does not lower, on an operand far
+beyond VMEM at LM width), so they run only in interpret mode and
+``fedagg_pallas`` refuses them for the chip.
 """
 from __future__ import annotations
 
@@ -50,90 +51,44 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 
-def _next_pow2(n: int) -> int:
-    return 1 if n <= 1 else 1 << (n - 1).bit_length()
+def order_stat_reduce(u, n, trim_frac=None):
+    """Coordinate-wise trimmed mean (``trim_frac`` set) or median (None) of
+    the n included rows of a [C, bm] f32 tile whose excluded rows hold
+    +inf, reduced over the client axis to [1, bm].
 
-
-def sort_cols_jnp(x):
-    """Ascending sort along axis 0 of [C, M] — the jnp-lowering twin of the
-    kernel's ``_sort_cols``: the SAME bitonic compare/exchange schedule,
-    unrolled in python with STATIC row permutations (illegal inside a
-    pallas kernel, which cannot capture the [P] index constants). Static
-    perms let XLA lower each exchange to vectorized row moves; the
-    fori_loop form costs ~1.5x more here, and XLA's own comparator sort
-    (jnp.sort) ~6x — it quicksorts every column at ~100 ns/compare, which
-    dominated whole training rounds at M ~ 2e4. Bit-identical to both
-    ``_sort_cols`` and jnp.sort (total order on floats; ties carry no
-    payload)."""
-    C = x.shape[0]
-    P = _next_pow2(C)
-    if P != C:
-        pad = jnp.full((P - C,) + x.shape[1:], jnp.inf, x.dtype)
-        x = jnp.concatenate([x, pad], axis=0)
-    idx = np.arange(P)
-    k = 2
-    while k <= P:
-        j = k // 2
-        while j >= 1:
-            px = x[idx ^ j]
-            lo = jnp.minimum(x, px)
-            hi = jnp.maximum(x, px)
-            take_lo = jnp.asarray((idx & k == 0) == (idx & j == 0))[:, None]
-            x = jnp.where(take_lo, lo, hi)
-            j //= 2
-        k *= 2
-    return x[:C]
-
-
-def _sort_cols(x):
-    """Ascending sort along axis 0 (clients) of a [C, bm] f32 block.
-
-    Bitonic compare/exchange network: rows are padded to a power of two
-    with +inf, every stage is a static-shape permute + min/max/where, so
-    the whole sort stays inside the grid cell (no HBM round-trip) and is
-    bit-identical to the jnp lowering's ``sort_cols_jnp``.
-    """
-    C = x.shape[0]
-    P = _next_pow2(C)
-    if P != C:
-        pad = jnp.full((P - C,) + x.shape[1:], jnp.inf, x.dtype)
-        x = jnp.concatenate([x, pad], axis=0)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (P, 1), 0)
-    # walk the (k, j) stage schedule with fori_loops (k and j derived from
-    # the loop indices by shifts) so the traced graph holds ONE
-    # compare/exchange body. Unrolling the log^2(P) stages instead makes
-    # XLA's CPU pipeline blow up on the gather chain (minutes at P=16,
-    # effectively forever at P=64); pallas kernels cannot capture a
-    # precomputed schedule array, hence the arithmetic form.
-    one = jnp.int32(1)
-
-    def pass_body(pi, x):                 # pass p = pi + 1: k = 2^p
-        k = jnp.left_shift(one, pi + 1)
-
-        def sub_body(qi, x):              # j = 2^(p-1), 2^(p-2), ..., 1
-            j = jnp.left_shift(one, pi - qi)
-            px = jnp.take_along_axis(
-                x, jnp.broadcast_to(idx ^ j, x.shape), axis=0)
-            lo = jnp.minimum(x, px)
-            hi = jnp.maximum(x, px)
-            asc = (idx & k) == 0          # direction of this bitonic block
-            first = (idx & j) == 0        # lower partner of the pair
-            return jnp.where(asc == first, lo, hi)
-
-        return jax.lax.fori_loop(0, pi + 1, sub_body, x)
-
-    n_passes = P.bit_length() - 1         # log2(P) static
-    return jax.lax.fori_loop(0, n_passes, pass_body, x)[:C]
+    Ranks instead of a sort: row i's rank in column m counts the rows that
+    order before it (smaller value, ties broken by row index), so the ranks
+    are a permutation and the included rows take ranks [0, n). C static
+    compare passes over the 2-D tile, with no permutes, gathers or 1-D
+    vectors, so the same code runs inside the Pallas grid cell (where
+    Mosaic accepts only such ops) and as the jnp lowering in
+    ``kernels/ops.py``. n == 0 (or no survivor) -> exact zero."""
+    C = u.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    rank = jnp.zeros(u.shape, jnp.int32)
+    for j in range(C):
+        uj = u[j:j + 1, :]
+        before = (uj < u) | ((uj == u) & (row > j))
+        rank = rank + before.astype(jnp.int32)
+    if trim_frac is None:
+        lo, hi = (n - 1) // 2, n // 2                          # even n: average
+        out = 0.5 * (jnp.sum(jnp.where(rank == lo, u, 0.0), axis=0, keepdims=True)
+                     + jnp.sum(jnp.where(rank == hi, u, 0.0), axis=0, keepdims=True))
+        return jnp.where(n > 0, out, 0.0)
+    t = (jnp.float32(trim_frac) * n.astype(jnp.float32)).astype(jnp.int32)
+    keep = (rank >= t) & (rank < n - t)                        # survivors
+    cnt = n - 2 * t
+    total = jnp.sum(jnp.where(keep, u, 0.0), axis=0, keepdims=True)
+    return jnp.where(cnt > 0, total / jnp.maximum(cnt, 1).astype(jnp.float32), 0.0)
 
 
 def _included_stats(g):
-    """Inclusion mask [C] bool and included count n (traced i32 scalar)."""
+    """Inclusion mask [C, 1] bool and included count n (traced i32 scalar)."""
     inc = g > 0
-    return inc, jnp.sum(inc.astype(jnp.int32))
+    return inc, jnp.sum(inc.astype(jnp.float32)).astype(jnp.int32)
 
 
 # --------------------------------------------------------- wire-codec decode
@@ -149,9 +104,9 @@ def _decode_identity(refs):
 
 
 def _decode_int8(refs):
-    # dequantize-in-register: int8 rows times the per-client f32 scale
+    # dequantize-in-register: int8 rows times the per-client [C, 1] scale
     u_ref, s_ref = refs
-    return u_ref[...].astype(jnp.float32) * s_ref[...].astype(jnp.float32)[:, None]
+    return u_ref[...].astype(jnp.float32) * s_ref[...].astype(jnp.float32)
 
 
 def _decode_topk(block_m, refs):
@@ -177,22 +132,28 @@ def _decode_topk(block_m, refs):
 
 def _decode_sketch(refs):
     # CountSketch estimate: gather each column's bucket from the [C, dim]
-    # sketch rows and apply its sign (0 on the padded tail, so padded
-    # columns decode to exact zero)
+    # sketch rows and apply its sign
     s_ref, h_ref, sg_ref = refs
     s = s_ref[...].astype(jnp.float32)                         # [C, dim]
-    h = h_ref[...]                                             # [bm] i32
-    sg = sg_ref[...].astype(jnp.float32)                       # [bm]
-    return jnp.take(s, h, axis=1) * sg[None, :]
+    h = h_ref[0, :]                                            # [bm] i32
+    sg = sg_ref[...].astype(jnp.float32)                       # [1, bm]
+    return jnp.take(s, h, axis=1) * sg
+
+
+def _weighted_mean(wg, scale, u):
+    """sum_k wg_k scale_k u_k / sum_k wg_k over [C, 1] columns and a
+    [C, bm] tile; gated-out rows (and their scales) are masked first."""
+    inc = wg > 0
+    den = jnp.sum(wg)
+    u = jnp.where(inc, u, 0.0)
+    num = jnp.sum(jnp.where(inc, wg * scale, 0.0) * u, axis=0, keepdims=True)
+    return num, den
 
 
 def _mean_kernel(decode, n_enc, *refs):
     w_ref, g_ref, o_ref = refs[n_enc], refs[n_enc + 1], refs[-1]
-    wg = (w_ref[...] * g_ref[...]).astype(jnp.float32)        # [C]
-    den = jnp.sum(wg)
-    u = jnp.where((wg > 0)[:, None], decode(refs[:n_enc]), 0.0)
-    num = jax.lax.dot_general(wg[None, :], u, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)[0]
+    wg = (w_ref[...] * g_ref[...]).astype(jnp.float32)        # [C, 1]
+    num, den = _weighted_mean(wg, 1.0, decode(refs[:n_enc]))
     out = jnp.where(den > 0, num / jnp.maximum(den, 1e-30), 0.0)
     o_ref[...] = out.astype(o_ref.dtype)
 
@@ -200,50 +161,32 @@ def _mean_kernel(decode, n_enc, *refs):
 def _dp_kernel(noise_scale, decode, n_enc, *refs):
     w_ref, g_ref = refs[n_enc], refs[n_enc + 1]
     s_ref, n_ref, o_ref = refs[n_enc + 2], refs[n_enc + 3], refs[-1]
-    wg = (w_ref[...] * g_ref[...]).astype(jnp.float32)        # [C]
-    den = jnp.sum(wg)
-    # clip scales, masked on excluded rows: a NaN delta in a gated-out
+    wg = (w_ref[...] * g_ref[...]).astype(jnp.float32)        # [C, 1]
+    # clip scales are masked on excluded rows: a NaN delta in a gated-out
     # client makes its row_scale NaN and 0 * NaN would leak through
-    wgs = jnp.where(wg > 0, wg * s_ref[...].astype(jnp.float32), 0.0)
-    u = jnp.where((wg > 0)[:, None], decode(refs[:n_enc]), 0.0)
-    num = jax.lax.dot_general(wgs[None, :], u, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)[0]
+    num, den = _weighted_mean(wg, s_ref[...].astype(jnp.float32),
+                              decode(refs[:n_enc]))
     safe = jnp.maximum(den, 1e-30)
     noisy = num / safe + n_ref[...].astype(jnp.float32) * (noise_scale / safe)
     o_ref[...] = jnp.where(den > 0, noisy, 0.0).astype(o_ref.dtype)
 
 
-def _trimmed_kernel(trim_frac, decode, n_enc, *refs):
+def _order_stat_kernel(trim_frac, decode, n_enc, *refs):
     g_ref, o_ref = refs[n_enc + 1], refs[-1]                   # unweighted
     inc, n = _included_stats(g_ref[...])
-    u = jnp.where(inc[:, None], decode(refs[:n_enc]), jnp.inf)
-    s = _sort_cols(u)                                          # [C, bm]
-    t = (jnp.float32(trim_frac) * n.astype(jnp.float32)).astype(jnp.int32)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
-    keep = (idx >= t) & (idx < n - t)                          # survivors
-    cnt = n - 2 * t
-    total = jnp.sum(jnp.where(keep, s, 0.0), axis=0)
-    out = jnp.where(cnt > 0, total / jnp.maximum(cnt, 1).astype(jnp.float32), 0.0)
-    o_ref[...] = out.astype(o_ref.dtype)
+    u = jnp.where(inc, decode(refs[:n_enc]), jnp.inf)
+    o_ref[...] = order_stat_reduce(u, n, trim_frac).astype(o_ref.dtype)
 
 
-def _median_kernel(decode, n_enc, *refs):
-    g_ref, o_ref = refs[n_enc + 1], refs[-1]                   # unweighted
-    inc, n = _included_stats(g_ref[...])
-    u = jnp.where(inc[:, None], decode(refs[:n_enc]), jnp.inf)
-    s = _sort_cols(u)
-    lo, hi = (n - 1) // 2, n // 2                              # even n: average
-    idx = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
-    med = 0.5 * (jnp.sum(jnp.where(idx == lo, s, 0.0), axis=0)
-                 + jnp.sum(jnp.where(idx == hi, s, 0.0), axis=0))
-    o_ref[...] = jnp.where(n > 0, med, 0.0).astype(o_ref.dtype)
+# codecs whose in-kernel decode has a Mosaic lowering (see module docstring)
+TPU_CODECS = ("identity", "int8")
 
 
 def fedagg_pallas(updates, weights, gates, *, block_m=2048, interpret=False,
                   aggregator="mean", trim_frac=0.0, row_scale=None,
                   noise=None, noise_scale=0.0, codec="identity",
                   dequant_scale=None, topk_idx=None, sketch_h=None,
-                  sketch_sign=None, out_m=None):
+                  sketch_sign=None, out_m=None, out_dtype=None):
     """updates: [C, M] (or the codec's wire shape); weights, gates: [C] -> [M].
 
     aggregator: mean | trimmed_mean | median | dp — one fused kernel launch
@@ -254,52 +197,62 @@ def fedagg_pallas(updates, weights, gates, *, block_m=2048, interpret=False,
     pre-pass upstream and lands here as plain ``mean``.
 
     ``codec`` selects the in-kernel wire decode, COMPOSED with every
-    aggregator in the same launch (decode feeds the mean/dp contraction
-    directly, and runs before the order-statistics sort):
+    aggregator in the same launch (decode feeds the mean/dp sum directly,
+    and runs before the order-statistics ranking):
 
-    - ``identity`` — ``updates`` is the dense [C, M] buffer (legacy path,
-      output in ``updates.dtype``).
+    - ``identity`` — ``updates`` is the dense [C, M] buffer (output in
+      ``updates.dtype``).
     - ``int8`` — ``updates`` is [C, M] int8; ``dequant_scale`` [C] f32
       dequantizes each row in-register after the tile load.
     - ``topk`` — ``updates`` is [C, k] f32 values with ``topk_idx``
       [C, k] i32 column indices (both full-array operands per cell);
       ``out_m`` gives the true M. Each cell scatter-accumulates its tile.
+      Interpret mode only.
     - ``sketch`` — ``updates`` is [C, dim] f32 CountSketch rows (full per
       cell); ``sketch_h`` / ``sketch_sign`` [M] are the shared hash/sign
-      planes (tiled per block); ``out_m`` gives the true M.
+      planes (tiled per block); ``out_m`` gives the true M. Interpret
+      mode only.
 
     Codec outputs are f32 (the wire dtype no longer matches the model).
-    The dense decode is never materialized in HBM — each grid cell decodes
-    its own [C, block_m] tile in VMEM. TPU caveat: the [C, k] / [C, dim]
-    full-array operands assume k resp. dim pad to lane multiples on real
-    hardware; CPU CI exercises interpret mode only, like every kernel
-    here."""
+    ``out_dtype`` overrides the output dtype; accumulation is f32 either
+    way. The dense decode is never materialized in HBM — each grid cell decodes
+    its own [C, block_m] tile in VMEM."""
+    if not interpret and codec not in TPU_CODECS:
+        raise NotImplementedError(
+            f"fedagg_pallas: wire codec {codec!r} has no compiled TPU "
+            f"kernel (only {TPU_CODECS} do); its decode gathers from a "
+            "whole [C, k]/[C, dim] operand per grid cell. Run it with "
+            "interpret=True, or pick a codec from that list")
     C = updates.shape[0]
     M = int(out_m) if out_m is not None else updates.shape[1]
-    out_dtype = updates.dtype if codec == "identity" else jnp.float32
-    block_m = min(block_m, M)
-    pad = (-M) % block_m
-    Mp = M + pad
-    nm = Mp // block_m
-    if pad and noise is not None:
-        noise = jnp.pad(noise, (0, pad))
+    if out_dtype is None:
+        out_dtype = updates.dtype if codec == "identity" else jnp.float32
+    # a ragged last tile is a partial block: Pallas clips its out-of-range
+    # columns on write, and every reduction here is column-local, so
+    # nothing is padded (a padded copy of [C, M_total] would cost a whole
+    # extra buffer of device memory at LM width)
+    block_m = min(block_m, -(-M // 128) * 128)
+    nm = pl.cdiv(M, block_m)
 
-    vec_spec = pl.BlockSpec((C,), lambda im: (0,))
-    col_spec = pl.BlockSpec((block_m,), lambda im: (im,))
+    def col(x):                       # per-client [C] vector -> [C, 1]
+        return jnp.reshape(x, (C, 1))
+
+    def row(x):                       # per-column [M] vector -> [1, M]
+        return jnp.reshape(x, (1, M))
+
+    vec_spec = pl.BlockSpec((C, 1), lambda im: (0, 0))
+    row_spec = pl.BlockSpec((1, block_m), lambda im: (0, im))
+    tile_spec = pl.BlockSpec((C, block_m), lambda im: (0, im))
 
     if codec == "identity":
-        if pad:
-            updates = jnp.pad(updates, ((0, 0), (0, pad)))
-        enc_specs = [pl.BlockSpec((C, block_m), lambda im: (0, im))]
+        enc_specs = [tile_spec]
         enc_ops = [updates]
         decode = _decode_identity
     elif codec == "int8":
         if dequant_scale is None:
             raise ValueError("codec='int8' needs dequant_scale [C]")
-        if pad:
-            updates = jnp.pad(updates, ((0, 0), (0, pad)))
-        enc_specs = [pl.BlockSpec((C, block_m), lambda im: (0, im)), vec_spec]
-        enc_ops = [updates, dequant_scale]
+        enc_specs = [tile_spec, vec_spec]
+        enc_ops = [updates, col(dequant_scale)]
         decode = _decode_int8
     elif codec == "topk":
         if topk_idx is None or out_m is None:
@@ -313,35 +266,31 @@ def fedagg_pallas(updates, weights, gates, *, block_m=2048, interpret=False,
         if sketch_h is None or sketch_sign is None or out_m is None:
             raise ValueError(
                 "codec='sketch' needs sketch_h [M], sketch_sign [M], out_m")
-        if pad:
-            # sign pads with 0 -> padded columns decode to exact zero
-            sketch_h = jnp.pad(sketch_h, (0, pad))
-            sketch_sign = jnp.pad(sketch_sign, (0, pad))
         dim = updates.shape[1]
         enc_specs = [pl.BlockSpec((C, dim), lambda im: (0, 0)),
-                     col_spec, col_spec]
-        enc_ops = [updates, sketch_h, sketch_sign]
+                     row_spec, row_spec]
+        enc_ops = [updates, row(sketch_h), row(sketch_sign)]
         decode = _decode_sketch
     else:
         raise ValueError(f"unknown wire codec {codec!r}")
 
     in_specs = enc_specs + [vec_spec, vec_spec]
-    operands = enc_ops + [weights, gates]
+    operands = enc_ops + [col(weights), col(gates)]
     n_enc = len(enc_ops)
     if aggregator == "mean":
         kernel = functools.partial(_mean_kernel, decode, n_enc)
     elif aggregator == "trimmed_mean":
-        kernel = functools.partial(_trimmed_kernel, float(trim_frac), decode,
-                                   n_enc)
+        kernel = functools.partial(_order_stat_kernel, float(trim_frac),
+                                   decode, n_enc)
     elif aggregator == "median":
-        kernel = functools.partial(_median_kernel, decode, n_enc)
+        kernel = functools.partial(_order_stat_kernel, None, decode, n_enc)
     elif aggregator == "dp":
         if row_scale is None or noise is None:
             raise ValueError("aggregator='dp' needs row_scale [C] and noise [M]")
         kernel = functools.partial(_dp_kernel, float(noise_scale), decode,
                                    n_enc)
-        in_specs += [vec_spec, col_spec]
-        operands += [row_scale, noise]
+        in_specs += [vec_spec, row_spec]
+        operands += [col(row_scale), row(noise)]
     else:
         raise ValueError(f"unknown in-kernel aggregator {aggregator!r}")
 
@@ -349,8 +298,8 @@ def fedagg_pallas(updates, weights, gates, *, block_m=2048, interpret=False,
         kernel,
         grid=(nm,),
         in_specs=in_specs,
-        out_specs=col_spec,
-        out_shape=jax.ShapeDtypeStruct((Mp,), out_dtype),
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((1, M), out_dtype),
         interpret=interpret,
     )(*operands)
-    return out[:M]
+    return out.reshape(M)

@@ -11,6 +11,10 @@ Layout notes (HBM->VMEM):
   out like q.
 hd is expected to be 64/96/128 (lane-aligned); block_q/block_kv multiples
 of 128 keep the MXU fed on the s = q @ k^T and p @ v contractions.
+
+Forward and backward compile for TPU v5e as written
+(``tests/test_tpu_compile.py``) and match ``ref.attention_ref`` on the
+chip to bf16 rounding (``chip_smoke.py``).
 """
 from __future__ import annotations
 

@@ -1,19 +1,69 @@
 """jit'd dispatch layer for the Pallas kernels.
 
-Every op has (a) a Pallas TPU kernel (``<name>.py``), (b) a production jnp
-fallback here (chunked / memory-safe, used on CPU and in dry-run lowering),
-and (c) a naive oracle in ``ref.py`` used by tests.
+Every op has (a) a Pallas TPU kernel (``<name>.py``), (b) a jnp lowering
+here (chunked / memory-safe), and (c) a naive oracle in ``ref.py`` used by
+tests.
 
-``use_pallas=True`` selects the Pallas path; on a CPU backend the Pallas
-kernels only run in ``interpret=True`` mode (tests do this explicitly).
+``flash_attention`` and ``fedagg`` take ``use_pallas=None`` by default,
+which follows the platform (``pallas_default``): the compiled Pallas
+kernels where the default backend is a TPU, the jnp lowerings elsewhere.
+Nothing falls back at run time: on a TPU an unsupported Pallas variant
+raises. ``decode_attention`` and ``ssm_scan`` keep the jnp lowering unless
+a caller passes ``use_pallas=True``, because their Pallas kernels do not
+compile for TPU yet (ROADMAP). On a CPU backend the Pallas kernels run
+only with ``interpret=True`` (tests do this explicitly).
+
+XLA cannot partition a Mosaic kernel, so on a multi-device mesh the
+caller places the kernels (``kernels_per_shard``): each runs per shard
+under ``jax.shard_map``.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
+
+
+def pallas_default() -> bool:
+    """True where the compiled Pallas kernels are the default path: the
+    default backend is a TPU (the only backend Mosaic compiles for)."""
+    return jax.default_backend() == "tpu"
+
+
+# (mesh, batch_axes, head_axes) while tracing under ``kernels_per_shard``
+_SHARDS = contextvars.ContextVar("kernel_shards", default=None)
+
+
+@contextlib.contextmanager
+def kernels_per_shard(mesh, batch_axes=(), head_axes=()):
+    """Trace the Pallas kernels inside this block per shard of ``mesh``
+    under ``jax.shard_map``. A kernel's batch dim (attention's B, fedagg's
+    client rows) splits over ``batch_axes`` and attention heads over
+    ``head_axes`` where they divide evenly; every other dim is whole on
+    each shard. The caller owns the placement: the pod rounds
+    (``fl/sharded.py``) name their data-parallel axes, and a body vmapped
+    over clients with ``spmd_axis_name`` names none, since its vmap maps
+    the client axis over them. ``mesh=None`` runs the kernels unwrapped."""
+    token = _SHARDS.set(None if mesh is None else
+                        (mesh, tuple(batch_axes), tuple(head_axes)))
+    try:
+        yield
+    finally:
+        _SHARDS.reset(token)
+
+
+def _split(mesh, axes, n):
+    """``axes`` as one spec entry when a dim of size ``n`` splits evenly
+    over them, else None (the dim is whole on every shard)."""
+    size = math.prod(mesh.shape[a] for a in axes)
+    return axes if axes and n % size == 0 else None
 
 
 # ============================================================ flash attention
@@ -77,13 +127,34 @@ def _flash_attention_jnp(q, k, v, *, causal, window, block_kv, kv_len=None,
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd).astype(q.dtype)
 
 
+def _flash_pallas_fits(q, k, kv_len, block=128):
+    """The Pallas kernel's domain: a full kv sequence (no ``kv_len``) and
+    Sq / Skv that are block multiples or fit one block."""
+    return kv_len is None and all(n <= block or n % block == 0
+                                  for n in (q.shape[1], k.shape[1]))
+
+
 def flash_attention(q, k, v, *, causal=True, window=0, block_kv=1024,
-                    kv_len=None, scale=None, use_pallas=False, interpret=False,
+                    kv_len=None, scale=None, use_pallas=None, interpret=False,
                     mm_dtype=None):
+    """``use_pallas=None`` runs the Pallas kernel on TPU for every call in
+    its domain (``_flash_pallas_fits``) and the blockwise jnp lowering for
+    the rest (ragged lengths such as whisper's 1500 frames)."""
+    if use_pallas is None:
+        use_pallas = pallas_default() and _flash_pallas_fits(q, k, kv_len)
     if use_pallas:
         from repro.kernels.flash_attention import flash_attention_pallas
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      kv_len=kv_len, scale=scale, interpret=interpret)
+        fn = functools.partial(flash_attention_pallas, causal=causal,
+                               window=window, kv_len=kv_len, scale=scale,
+                               interpret=interpret)
+        shards = _SHARDS.get()
+        if shards is not None:
+            mesh, batch_axes, head_axes = shards
+            spec = P(_split(mesh, batch_axes, q.shape[0]), None,
+                     _split(mesh, head_axes, k.shape[2]), None)
+            fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=spec, check_vma=False)
+        return fn(q, k, v)
     return _flash_attention_jnp(q, k, v, causal=causal, window=window,
                                 block_kv=block_kv, kv_len=kv_len, scale=scale,
                                 mm_dtype=mm_dtype)
@@ -142,28 +213,14 @@ def _fedagg_sorted_jnp(updates, gates, *, trim_frac=None):
     INCLUDED clients, unweighted — the Byzantine-robust convention (Yin et
     al., arXiv:1803.01498). Excluded clients sort to +inf, so the n included
     values occupy sorted positions [0, n). n == 0 -> exact zero."""
-    from repro.kernels.fedagg import sort_cols_jnp
+    from repro.kernels.fedagg import order_stat_reduce
 
-    C = updates.shape[0]
     inc = gates > 0
     n = jnp.sum(inc.astype(jnp.int32))
     u = jnp.where(inc[:, None], updates.astype(jnp.float32), jnp.inf)
-    # the kernel's bitonic network (static-perm unrolling), not jnp.sort —
-    # see sort_cols_jnp for why XLA's comparator sort is ~6x slower here
-    s = sort_cols_jnp(u)
-    idx = jnp.arange(C, dtype=jnp.int32)[:, None]
-    if trim_frac is None:                                      # median
-        lo, hi = (n - 1) // 2, n // 2
-        med = 0.5 * (jnp.sum(jnp.where(idx == lo, s, 0.0), axis=0)
-                     + jnp.sum(jnp.where(idx == hi, s, 0.0), axis=0))
-        out = jnp.where(n > 0, med, 0.0)
-    else:
-        t = (jnp.float32(trim_frac) * n.astype(jnp.float32)).astype(jnp.int32)
-        keep = (idx >= t) & (idx < n - t)
-        cnt = n - 2 * t
-        total = jnp.sum(jnp.where(keep, s, 0.0), axis=0)
-        out = jnp.where(cnt > 0, total / jnp.maximum(cnt, 1).astype(jnp.float32), 0.0)
-    return out.astype(updates.dtype)
+    # the kernel's own rank-and-select, not jnp.sort: XLA's comparator
+    # sort quicksorts every column and dominated whole CPU rounds
+    return order_stat_reduce(u, n, trim_frac)[0].astype(updates.dtype)
 
 
 def _decode_wire_jnp(updates, *, codec, dequant_scale=None, topk_idx=None,
@@ -194,7 +251,7 @@ def _decode_wire_jnp(updates, *, codec, dequant_scale=None, topk_idx=None,
     raise ValueError(f"unknown wire codec {codec!r}")
 
 
-def fedagg(updates, weights, gates, *, use_pallas=False, interpret=False,
+def fedagg(updates, weights, gates, *, use_pallas=None, interpret=False,
            block_m=2048, aggregator="mean", trim_frac=0.0, row_scale=None,
            noise=None, noise_scale=0.0, codec="identity", dequant_scale=None,
            topk_idx=None, sketch_h=None, sketch_sign=None, out_m=None):
@@ -211,20 +268,36 @@ def fedagg(updates, weights, gates, *, use_pallas=False, interpret=False,
 
     ``codec`` (identity | int8 | topk | sketch) composes the wire decode
     with the reduction: on the Pallas path the decode happens per grid
-    cell inside the same launch (no dense decode buffer in HBM); on this
-    jnp fallback the buffer is decoded then reduced. Non-identity codecs
+    cell inside the same launch (no dense decode buffer in HBM); on the
+    jnp lowering the buffer is decoded then reduced. Non-identity codecs
     output f32 regardless of the wire dtype; the extra operands
     (``dequant_scale``, ``topk_idx``, ``sketch_h``/``sketch_sign``,
-    ``out_m``) are supplied by the codec's encode (core/aggregation.py)."""
+    ``out_m``) are supplied by the codec's encode (core/aggregation.py).
+
+    ``use_pallas=None`` follows the platform (``pallas_default``)."""
+    if use_pallas is None:
+        use_pallas = pallas_default()
     if use_pallas:
         from repro.kernels.fedagg import fedagg_pallas
-        return fedagg_pallas(updates, weights, gates, block_m=block_m,
-                             interpret=interpret, aggregator=aggregator,
-                             trim_frac=trim_frac, row_scale=row_scale,
-                             noise=noise, noise_scale=noise_scale,
-                             codec=codec, dequant_scale=dequant_scale,
-                             topk_idx=topk_idx, sketch_h=sketch_h,
-                             sketch_sign=sketch_sign, out_m=out_m)
+        kernel = functools.partial(
+            fedagg_pallas, block_m=block_m, interpret=interpret,
+            aggregator=aggregator, trim_frac=trim_frac,
+            noise_scale=noise_scale, codec=codec, out_m=out_m)
+        # operands with a leading client axis, and per-column ones
+        rows = {k: x for k, x in dict(
+            updates=updates, weights=weights, gates=gates,
+            row_scale=row_scale, dequant_scale=dequant_scale,
+            topk_idx=topk_idx).items() if x is not None}
+        cols = {k: x for k, x in dict(
+            noise=noise, sketch_h=sketch_h,
+            sketch_sign=sketch_sign).items() if x is not None}
+        shards = _SHARDS.get()
+        if shards is None:
+            return kernel(**rows, **cols)
+        mesh, batch_axes, _ = shards
+        return _fedagg_on_mesh(
+            mesh, batch_axes, kernel, aggregator == "mean", rows, cols,
+            updates.dtype if codec == "identity" else jnp.float32)
     if codec != "identity":
         updates = _decode_wire_jnp(updates, codec=codec,
                                    dequant_scale=dequant_scale,
@@ -242,6 +315,34 @@ def fedagg(updates, weights, gates, *, use_pallas=False, interpret=False,
         return _fedagg_dp_jnp(updates, weights, gates, row_scale, noise,
                               float(noise_scale))
     raise ValueError(f"unknown in-kernel aggregator {aggregator!r}")
+
+
+def _fedagg_on_mesh(mesh, batch_axes, kernel, linear, rows, cols, out_dtype):
+    """The fedagg kernel on a multi-device mesh. The gated mean is linear:
+    each shard reduces its own clients (rows split over ``batch_axes``) to
+    an f32 partial mean, and one f32 all-reduce of the mass-weighted
+    partials (``den_s * out_s``) and masses finishes it; the result is
+    cast to ``out_dtype`` once, after the sum, as the single-device
+    kernel does. The order statistics and dp need every
+    client at once, so they gather the client axis and reduce on every
+    shard (the documented client-axis gather of the pod rounds)."""
+    split = (_split(mesh, batch_axes, rows["updates"].shape[0])
+             if linear else None)
+
+    def body(rows, cols):
+        if split is None:
+            return kernel(**rows, **cols)
+        out = kernel(**rows, **cols, out_dtype=jnp.float32)
+        den = jnp.sum((rows["weights"] * rows["gates"]).astype(jnp.float32))
+        num = jax.lax.psum(den * out, split)
+        den = jax.lax.psum(den, split)
+        return jnp.where(den > 0, num / jnp.maximum(den, 1e-30), 0.0)
+
+    in_specs = (jax.tree.map(lambda _: P(split), rows),
+                jax.tree.map(lambda _: P(), cols))
+    out = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                        out_specs=P(), check_vma=False)(rows, cols)
+    return out.astype(out_dtype)
 
 
 # ==================================================================== rmsnorm

@@ -6,15 +6,13 @@ extraction for the roofline (EXPERIMENTS.md SS Dry-run / SS Roofline).
         --shape train_4k [--multi-pod] [--out results/dryrun]
 
 No real arrays are ever allocated: params/batches/caches enter as
-jax.ShapeDtypeStruct with NamedShardings attached.
+jax.ShapeDtypeStruct with NamedShardings attached. ``main`` gives the CPU
+backend 512 host devices; importing this module changes nothing.
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count at first init).
-
 import argparse
 import gzip
 import json
+import os
 import re
 import time
 import traceback
@@ -32,7 +30,8 @@ from repro.launch.mesh import make_production_mesh
 from repro.models import get_model
 from repro.sharding.specs import (auto_batch_specs, auto_param_specs,
                                   auto_tree_specs, dp_axes,
-                                  federation_state_specs, shaped_with)
+                                  federation_state_specs, round_batch_specs,
+                                  shaped_with)
 from repro.utils import param_count
 
 # shape-point skips with reasons (DESIGN.md SS4)
@@ -108,14 +107,10 @@ def build_train(cfg, shape, mesh, fed=DRYRUN_FED):
     dpsize = int(np.prod([mesh.shape[a] for a in dp]))
     B, S = shape.global_batch, shape.seq_len
 
-    if fsdp:    # temporal: cohort scanned, inner batch sharded over dp
-        C = TEMPORAL_COHORT
-        b = B // C
-        cspec_prefix = (None, dp)
-    else:       # spatial: clients = dp shards
-        C = dpsize
-        b = B // C
-        cspec_prefix = (dp, None)
+    # temporal: cohort scanned, inner batch sharded over dp;
+    # spatial: clients = dp shards
+    C = TEMPORAL_COHORT if fsdp else dpsize
+    b = B // C
 
     clients = _token_batch_shapes(cfg, C, b, S, stacked=True)
     server = _token_batch_shapes(cfg, None, min(b, 8) * 1, S, stacked=False)
@@ -126,22 +121,7 @@ def build_train(cfg, shape, mesh, fed=DRYRUN_FED):
         "weights": jax.ShapeDtypeStruct((C,), jnp.float32),
     }
 
-    def batch_spec(leaf, *, is_client):
-        nd = len(leaf.shape)
-        if not is_client:
-            sp = [None] * nd
-            if leaf.shape and leaf.shape[0] % dpsize == 0 and leaf.shape[0] >= dpsize:
-                sp[0] = dp
-            return P(*sp)
-        sp = list(cspec_prefix) + [None] * (nd - 2)
-        return P(*sp)
-
-    batch_specs = {
-        "clients": jax.tree.map(lambda l: batch_spec(l, is_client=True), clients),
-        "server": jax.tree.map(lambda l: batch_spec(l, is_client=False), server),
-        "priority_mask": P(),
-        "weights": P(),
-    }
+    batch_specs = round_batch_specs(batch_shapes, mesh, fsdp=fsdp)
 
     param_shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     param_specs = auto_param_specs(param_shapes, mesh, fsdp=fsdp,
@@ -336,6 +316,8 @@ def build_parser():
 
 
 def main():
+    # before the first backend use: jax fixes the device count then
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     args = build_parser().parse_args()
 
     # a default command line yields {} -> fed stays LITERALLY DRYRUN_FED,

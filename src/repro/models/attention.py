@@ -2,8 +2,8 @@
 
 The train/prefill path is an online-softmax blockwise attention written with
 ``lax.scan`` so that no [S, S] score matrix is ever materialized — this is
-the jnp twin of the Pallas ``flash_attention`` kernel (kernels/ops.py swaps
-the Pallas version in on TPU).
+the jnp twin of the Pallas ``flash_attention`` kernel (kernels/ops.py runs
+the Pallas version on TPU).
 """
 from __future__ import annotations
 
@@ -76,7 +76,7 @@ def gqa_attention_block(p, x, cfg, *, positions, mode, cache=None, dispatch=None
             v = jax.lax.with_sharding_constraint(v, P(dp, None, None, None))
         out = kops.flash_attention(q, k, v, causal=cfg.causal, window=window,
                                    block_kv=cfg.attn_block_kv,
-                                   use_pallas=cfg.use_pallas, mm_dtype=mm_dtype)
+                                   mm_dtype=mm_dtype)
         if cfg.seq_shard_attn:
             from jax.sharding import PartitionSpec as P
             dp = cfg.dp_axes if len(cfg.dp_axes) > 1 else cfg.dp_axes[0]
@@ -98,8 +98,7 @@ def gqa_attention_block(p, x, cfg, *, positions, mode, cache=None, dispatch=None
         k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
         v_cache = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
         kv_len = jnp.minimum(pos + 1, W).astype(jnp.int32)
-        out = kops.decode_attention(q, k_cache, v_cache, kv_len=kv_len,
-                                    use_pallas=cfg.use_pallas)
+        out = kops.decode_attention(q, k_cache, v_cache, kv_len=kv_len)
         new_cache = {"k": k_cache, "v": v_cache, "len": kv_len}
 
     B_, S_, H, hd = out.shape
@@ -163,7 +162,7 @@ def mla_attention_block(p, x, cfg, *, positions, mode, cache=None, dispatch=None
         v_p = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad))) if pad > 0 else v
         out = kops.flash_attention(qfull, k, v_p, causal=cfg.causal,
                                    window=cfg.sliding_window,
-                                   block_kv=cfg.attn_block_kv, use_pallas=cfg.use_pallas)
+                                   block_kv=cfg.attn_block_kv)
         out = out[..., :vd]
         new_cache = None
         if mode == "prefill":

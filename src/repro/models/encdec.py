@@ -75,11 +75,11 @@ def _self_attn(p, x, cfg, *, causal, positions=None, mode="train", cache=None):
         kc = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
         vc = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
         kv_len = jnp.minimum(pos + 1, kc.shape[1]).astype(jnp.int32)
-        out = kops.decode_attention(q, kc, vc, kv_len=kv_len, use_pallas=cfg.use_pallas)
+        out = kops.decode_attention(q, kc, vc, kv_len=kv_len)
         new_cache = {"k": kc, "v": vc, "len": kv_len}
     else:
         out = kops.flash_attention(q, k, v, causal=causal,
-                                   block_kv=cfg.attn_block_kv, use_pallas=cfg.use_pallas)
+                                   block_kv=cfg.attn_block_kv)
         if mode == "prefill":
             new_cache = {"k": k, "v": v, "len": jnp.asarray(S, jnp.int32)}
     y = out.reshape(B, S, H * hd) @ p["wo"].astype(cd)

@@ -62,8 +62,7 @@ def mamba_block(p, x, cfg, *, mode, cache=None):
         xc = jax.nn.silu(_causal_conv(xi, p["conv_w"].astype(cd), p["conv_b"].astype(cd), K))
         dt, Bm, Cm = _ssm_inputs(p, xc, cfg)
         A = -jnp.exp(p["A_log"])
-        y = kops.ssm_scan(xc, dt, A, Bm, Cm, p["D"], chunk=cfg.ssm_chunk,
-                          use_pallas=cfg.use_pallas)
+        y = kops.ssm_scan(xc, dt, A, Bm, Cm, p["D"], chunk=cfg.ssm_chunk)
         new_cache = None
         if mode == "prefill":
             # replay the tail to produce the decode cache state

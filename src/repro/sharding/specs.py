@@ -39,6 +39,11 @@ def dp_axes(mesh: Mesh) -> tuple:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
+def tp_axes(mesh: Mesh) -> tuple:
+    """Mesh axes carrying tensor (model) parallelism."""
+    return tuple(a for a in ("model",) if a in mesh.axis_names)
+
+
 def _stack_offset(path) -> int:
     """Leaves under 'periods' / stacked inits carry a leading stack axis."""
     for k in path:
@@ -183,6 +188,23 @@ def auto_tree_specs(shapes, mesh: Mesh, *, prefer_batch_dim: int = 0,
     treedef = jax.tree_util.tree_structure(shapes)
     return jax.tree_util.tree_unflatten(
         treedef, [one(p, l) for p, l in paths_leaves])
+
+
+def round_batch_specs(batch_shapes, mesh: Mesh, *, fsdp: bool):
+    """PartitionSpecs for a pod round's batch (``launch.train.build_batches``
+    layout). Client leaves are [C, b, ...]: the spatial round shards the
+    client axis C over (pod, data) — each shard trains its own clients —
+    and the temporal round scans C and shards the inner batch b instead.
+    Server leaves shard their batch dim; the [C] priority mask and weights
+    replicate. Any dim not divisible by the data-parallel size replicates."""
+    client_dim = 1 if fsdp else 0
+    return {
+        "clients": auto_batch_specs(batch_shapes["clients"], mesh,
+                                    batch_dim=client_dim),
+        "server": auto_batch_specs(batch_shapes["server"], mesh),
+        "priority_mask": P(),
+        "weights": P(),
+    }
 
 
 def shaped_with(shapes, specs, mesh: Mesh):

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+import os
 import zlib
 from typing import Any
 
@@ -121,6 +122,23 @@ def split_like(key: jax.Array, names: list[str]) -> dict[str, jax.Array]:
 def has_nan(tree: Pytree) -> jax.Array:
     leaves = [jnp.any(jnp.isnan(x)) for x in jax.tree.leaves(tree) if jnp.issubdtype(x.dtype, jnp.floating)]
     return functools.reduce(jnp.logical_or, leaves, jnp.asarray(False))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here. Otherwise the cache lives at ``<checkout>/.jax_cache``,
+    a fixed path: the path is part of the cache key, so a temporary or
+    per-process directory would never hit. Called from entry points only,
+    never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def ceil_div(a: int, b: int) -> int:

@@ -237,6 +237,31 @@ def test_fedagg_codec_all_rows_zero():
                                           np.zeros((M,), np.float32))
 
 
+def test_fedagg_pallas_refuses_codecs_without_a_tpu_kernel():
+    """topk / sketch decode only in interpret mode; compiled (TPU) calls
+    raise instead of quietly running the jnp lowering."""
+    from repro.configs.base import FedConfig
+    from repro.core.aggregation import get_wire_codec
+
+    fed = FedConfig(codec_topk_frac=0.1, codec_sketch_dim=32, seed=3)
+    u = rand((3, 200), k=31)
+    w, g = jnp.ones((3,)), jnp.ones((3,))
+    for codec in ("topk", "sketch"):
+        enc, kw = get_wire_codec(codec).encode(fed, u)
+        with pytest.raises(NotImplementedError, match=codec):
+            fedagg_pallas(enc, w, g, **kw)
+
+
+def test_fedagg_platform_default_is_the_jnp_lowering_off_tpu():
+    """``use_pallas=None`` follows the platform: off a TPU no Pallas kernel
+    is traced."""
+    assert not ops.pallas_default()
+    u = rand((3, 300), k=32)
+    w, g = jnp.ones((3,)), jnp.ones((3,))
+    jaxpr = jax.make_jaxpr(lambda u: ops.fedagg(u, w, g))(u)
+    assert "pallas_call" not in str(jaxpr)
+
+
 # -------------------------------------------------------------------- rmsnorm
 @pytest.mark.parametrize("shape", [(4, 37, 128), (2, 256), (1, 5, 7, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -248,3 +248,212 @@ def test_train_driver_end_to_end():
                              clients=4, n_priority=2, per_client=2, seq=32,
                              verbose=False)
     assert hist[-1]["server_loss"] < hist[0]["server_loss"] + 0.5
+
+
+class _Compiled:
+    """Stands in for a compiled round: only its ``memory_analysis``."""
+
+    def __init__(self, args, temp, out=0, alias=0):
+        from types import SimpleNamespace
+        self.mem = SimpleNamespace(argument_size_in_bytes=args,
+                                   temp_size_in_bytes=temp,
+                                   output_size_in_bytes=out,
+                                   alias_size_in_bytes=alias)
+
+    def memory_analysis(self):
+        return self.mem
+
+
+@pytest.mark.parametrize("case", ["fits", "too_big", "compile_oom",
+                                  "no_limit", "fsdp_arch"])
+def test_round_choice_follows_device_memory(case):
+    """``choose_round`` compiles the spatial round and keeps it where its
+    program (args + temp + unaliased out) fits the device's limit; where
+    it does not, or the compiler runs out of device memory, it compiles
+    the temporal round. No reported limit (CPU) keeps the spatial round;
+    the archs ``needs_fsdp`` names never try it."""
+    from repro.configs import get_config
+    cfg = get_config("qwen1.5-0.5b")
+    limit = 16 * 10**9
+    spatial = _Compiled(args=2 * 10**9, temp=6 * 10**9, out=2 * 10**9,
+                        alias=2 * 10**9)
+    if case == "too_big":
+        spatial = _Compiled(args=2 * 10**9, temp=15 * 10**9)
+    temporal = _Compiled(args=2 * 10**9, temp=8 * 10**9)
+    tried = []
+
+    def compile_round(fsdp):
+        tried.append(fsdp)
+        if not fsdp and case == "compile_oom":
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran "
+                "out of memory in memory space hbm.")
+        return temporal if fsdp else spatial
+
+    if case == "no_limit":
+        limit = None
+        spatial = _Compiled(args=2 * 10**9, temp=10**12)
+    if case == "fsdp_arch":
+        cfg = get_config("llava-next-34b")
+    fsdp, compiled = sharded.choose_round(cfg, compile_round, limit)
+    want = {"fits": [False], "no_limit": [False], "too_big": [False, True],
+            "compile_oom": [False, True], "fsdp_arch": [True]}[case]
+    assert tried == want
+    assert fsdp is want[-1]
+    assert compiled is (temporal if fsdp else spatial)
+
+
+def test_round_choice_reraises_other_compile_errors():
+    """Only running out of device memory moves the choice on; any other
+    compile failure propagates."""
+    from repro.configs import get_config
+
+    def compile_round(fsdp):
+        raise jax.errors.JaxRuntimeError("INVALID_ARGUMENT: bad program")
+
+    with pytest.raises(jax.errors.JaxRuntimeError, match="INVALID_ARGUMENT"):
+        sharded.choose_round(get_config("qwen1.5-0.5b"), compile_round,
+                             16 * 10**9)
+
+
+def test_train_driver_records():
+    """The driver's history carries gates, timed seconds and (round 0)
+    compile seconds and the round it chose: spatial on a CPU, which
+    reports no memory limit."""
+    _, hist = train_run(arch="qwen1.5-0.5b", smoke=True, rounds=2, clients=4,
+                        n_priority=2, per_client=2, seq=32, verbose=False)
+    assert hist[0]["compile_sec"] > 0 and "compile_sec" not in hist[1]
+    assert hist[0]["round_mode"] == "spatial"
+    for rec in hist:
+        assert rec["sec"] > 0 and rec["gates"].shape == (4,)
+        assert np.isfinite(rec["server_loss"])
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache is the checkout's fixed ``.jax_cache``."""
+    import os
+    from repro.utils import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+MESH_CHILD = r"""
+import functools
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.kernels import ops, ref
+from repro.launch.mesh import make_host_mesh
+from repro.launch.train import run
+mesh = make_host_mesh()
+assert mesh.shape == {"data": 4, "model": 1}
+rows = NamedSharding(mesh, P("data"))
+
+
+def on_mesh(fn, *args, batch_axes=("data",)):
+    with jax.set_mesh(mesh), ops.kernels_per_shard(mesh, batch_axes):
+        return jax.jit(fn)(*args)
+
+
+# fedagg: the mean reduces per shard + one all-reduce, the order
+# statistics gather the client axis
+key = jax.random.PRNGKey(0)
+u = jax.device_put(jax.random.normal(key, (4, 3000)), rows)
+w, g = jnp.array([.1, .4, .3, .2]), jnp.array([1., 0., 1., 1.])
+wants = {"mean": ref.fedagg_ref(u, w, g),
+         "median": ref.fedagg_median_ref(u, w, g),
+         "trimmed_mean": ref.fedagg_trimmed_ref(u, w, g, 0.25)}
+for agg, want in wants.items():
+    got = on_mesh(functools.partial(ops.fedagg, use_pallas=True,
+                                    interpret=True, aggregator=agg,
+                                    trim_frac=0.25), u, w, g)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+# a bf16 wire with two clients per shard: the partial means stay f32 and
+# the mean is rounded to bf16 once, as on one device — both within half a
+# bf16 ulp of the f32 mean
+ub = (3 * jax.random.normal(jax.random.fold_in(key, 3), (8, 3000))
+      ).astype(jnp.bfloat16)
+w8 = jax.random.uniform(jax.random.fold_in(key, 4), (8,)) + 0.05
+g8 = jnp.array([1., 1., 0., 1., 1., 1., 1., 0.])
+exact = np.asarray(ref.fedagg_ref(ub.astype(jnp.float32), w8, g8))
+half_ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(exact), 1e-30))) - 8)
+single = ops.fedagg(ub, w8, g8, use_pallas=True, interpret=True)
+sharded = on_mesh(functools.partial(ops.fedagg, use_pallas=True,
+                                    interpret=True),
+                  jax.device_put(ub, rows), w8, g8)
+for got in (single, sharded):
+    assert got.dtype == jnp.bfloat16
+    err = np.abs(np.asarray(got, np.float32) - exact)
+    assert np.all(err <= half_ulp + 1e-6 * np.abs(exact)), err.max()
+
+# flash attention, batch split over data
+q, k = (jax.device_put(jax.random.normal(jax.random.fold_in(key, i),
+                                         (4, 128, 4, 32)), rows)
+        for i in (1, 2))
+grads = lambda attn: jax.grad(lambda q, k, v: attn(q, k, v).sum(),
+                              argnums=(0, 1, 2))
+flash = functools.partial(ops.flash_attention, use_pallas=True,
+                          interpret=True)
+got = on_mesh(grads(flash), q, k, 0.5 * q)
+for a, b in zip(got, jax.jit(grads(ref.attention_ref))(q, k, 0.5 * q)):
+    np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+# flash attention inside a client vmap whose client axis maps over data:
+# the kernel stays client-local, so no activation crosses devices
+qc, kc = (jax.device_put(jax.random.normal(jax.random.fold_in(key, i),
+                                           (4, 2, 128, 4, 32)), rows)
+          for i in (5, 6))
+local = jax.vmap(grads(flash), spmd_axis_name=("data",))
+with jax.set_mesh(mesh), ops.kernels_per_shard(mesh):
+    step = jax.jit(local).lower(qc, kc, 0.5 * qc).compile()
+    got = step(qc, kc, 0.5 * qc)
+hlo = step.as_text()
+assert "all-to-all" not in hlo and "all-gather" not in hlo
+for a, b in zip(got, jax.jit(jax.vmap(grads(ref.attention_ref)))(
+        qc, kc, 0.5 * qc)):
+    np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+# the spatial round through the trainer: four devices (one client each)
+# against one device, same seed and batches
+kw = dict(arch="qwen1.5-0.5b", smoke=True, rounds=2, clients=4,
+          n_priority=2, per_client=2, seq=32, verbose=False)
+p4, h4 = run(**kw, mesh=mesh)
+p1, h1 = run(**kw, mesh=make_host_mesh(devices=jax.devices()[:1]))
+assert h4[0]["round_mode"] == h1[0]["round_mode"] == "spatial"
+for a, b in zip(h4, h1):
+    np.testing.assert_array_equal(a["gates"], b["gates"])
+    np.testing.assert_allclose(a["server_loss"], b["server_loss"], rtol=1e-5)
+for a, b in zip(jax.tree.leaves(p4), jax.tree.leaves(p1)):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                               rtol=5e-5)
+print("OK")
+"""
+
+
+def test_kernels_run_per_shard_on_a_multi_device_mesh():
+    """XLA cannot partition a Mosaic kernel, so on a multi-device mesh the
+    Pallas fedagg / flash attention run per shard under shard_map (the mean
+    all-reduces f32 partial sums), client-local inside a client vmap; the
+    spatial round on four devices matches one device. Four host devices
+    need their own process; the kernels run in interpret mode there."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(root, "src"))
+    out = subprocess.run([sys.executable, "-c", MESH_CHILD], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0 and "OK" in out.stdout, out.stderr[-3000:]

@@ -11,8 +11,12 @@ from repro.models import get_model
 from repro.sharding.specs import (auto_batch_specs, auto_param_specs,
                                   auto_tree_specs, federation_state_specs)
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+
+def _mesh(kind="single"):
+    """The production mesh, described without devices."""
+    if kind == "multi":
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _check_divisible(shapes, specs, mesh):
@@ -28,8 +32,9 @@ def _check_divisible(shapes, specs, mesh):
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
-@pytest.mark.parametrize("mesh", [MESH, MESH3], ids=["single", "multi"])
-def test_param_specs_divisible_full_configs(arch, mesh):
+@pytest.mark.parametrize("kind", ["single", "multi"])
+def test_param_specs_divisible_full_configs(arch, kind):
+    mesh = _mesh(kind)
     cfg = get_config(arch)
     model = get_model(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -42,7 +47,7 @@ def test_model_axis_used_on_big_weights():
     cfg = get_config("qwen1_5_0_5b")
     model = get_model(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    specs = auto_param_specs(shapes, MESH)
+    specs = auto_param_specs(shapes, _mesh())
     # attention projections must be tensor-parallel
     wq_spec = specs["periods"]["l0"]["attn"]["wq"]
     assert "model" in tuple(wq_spec)
@@ -54,7 +59,7 @@ def test_fsdp_adds_data_axis():
     cfg = get_config("llava_next_34b")
     model = get_model(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    specs_f = auto_param_specs(shapes, MESH, fsdp=True)
+    specs_f = auto_param_specs(shapes, _mesh(), fsdp=True)
     leaves = jax.tree.leaves(jax.tree.map(
         lambda s: int("data" in [a for a in s if a]), specs_f,
         is_leaf=lambda s: isinstance(s, P)))
@@ -64,7 +69,7 @@ def test_fsdp_adds_data_axis():
 def test_batch_specs():
     shapes = {"tokens": jax.ShapeDtypeStruct((256, 4096), jnp.int32),
               "odd": jax.ShapeDtypeStruct((3, 5), jnp.float32)}
-    specs = auto_batch_specs(shapes, MESH)
+    specs = auto_batch_specs(shapes, _mesh())
     assert specs["tokens"] == P(("data",), None) or specs["tokens"] == P(("data",),) \
         or specs["tokens"][0] == ("data",)
     assert all(s is None for s in specs["odd"])
@@ -74,16 +79,16 @@ def test_cache_specs_divisible():
     cfg = get_config("qwen2_5_3b")       # KV=2: model axis must NOT land on KV
     model = get_model(cfg)
     shapes = jax.eval_shape(lambda: model.make_cache(128, 32768))
-    specs = auto_tree_specs(shapes, MESH)
-    _check_divisible(shapes, specs, MESH)
+    specs = auto_tree_specs(shapes, _mesh())
+    _check_divisible(shapes, specs, _mesh())
 
 
 def test_cache_specs_batch_one():
     cfg = get_config("xlstm_125m")
     model = get_model(cfg)
     shapes = jax.eval_shape(lambda: model.make_cache(1, 524288))
-    specs = auto_tree_specs(shapes, MESH)
-    _check_divisible(shapes, specs, MESH)
+    specs = auto_tree_specs(shapes, _mesh())
+    _check_divisible(shapes, specs, _mesh())
 
 
 @pytest.mark.parametrize("server_opt,kw", [
@@ -99,7 +104,7 @@ def test_federation_state_specs_match_state_tree(server_opt, kw):
     model = get_model(cfg)
     fed = FedConfig(server_opt=server_opt, **kw)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    pspecs = auto_param_specs(shapes, MESH)
+    pspecs = auto_param_specs(shapes, _mesh())
     state_shapes = jax.eval_shape(lambda p: engine.init_state(p, fed, 8),
                                   shapes)
     sspecs = federation_state_specs(fed, pspecs)
@@ -114,7 +119,7 @@ def test_expert_parallel_toggle():
     cfg = get_config("jamba_1_5_large_398b")   # 16 experts == model axis
     model = get_model(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    sp = auto_param_specs(shapes, MESH, expert_parallel=True)
+    sp = auto_param_specs(shapes, _mesh(), expert_parallel=True)
     moe_spec = sp["periods"]["l1"]["moe"]["w_gate"]
     # stacked periods axis + expert axis
     assert jax.tree.leaves(moe_spec)[0] is None or True
