@@ -56,6 +56,19 @@ are known. The opt-in is explicit because the sketch is JL-approximate:
 with it set, the spatial round scores on the same sketches, so the two
 modes stay gate-identical; without it, exact cosines exist only
 spatially and the temporal round refuses rather than silently diverge.
+
+Each phase of a round runs under a named scope (``jax.named_scope``),
+so a profile names the phase that spent each second:
+``fedalign.server_loss`` (the server statistic F(w_t)),
+``fedalign.eval`` (the clients' loss of the received model),
+``fedalign.gate`` (utility, gates, cohort and pool selection, failure
+masks, delta sketches), ``fedalign.train`` (E local steps),
+``fedalign.aggregate`` (the streamed carry or ``engine.server_delta``)
+and ``fedalign.server_step`` (the divergence guard, the server optimizer
+or in-flight buffer, the next state and stats). Phases never nest: a
+scan whose body holds several phases is called outside any of them, so
+its loop bookkeeping carries none. The scopes only name operations; the
+compiled program is the same with or without them.
 """
 from __future__ import annotations
 
@@ -77,6 +90,7 @@ from repro.sharding.specs import dp_axes, tp_axes
 from repro.utils import fold_in_name, tree_axpy, tree_sub
 
 FSDP_ARCHS = {"jamba-1.5-large-398b", "llava-next-34b"}
+PHASES = ("server_loss", "eval", "gate", "train", "aggregate", "server_step")
 
 
 def needs_fsdp(cfg) -> bool:
@@ -155,6 +169,13 @@ def _client_vmap(fn):
     return mapped
 
 
+def _phase(name):
+    """The named scope of one round phase, ``fedalign.<name>``. Each call
+    site scopes its own phase; a scoped region holds no other phase."""
+    assert name in PHASES, name
+    return jax.named_scope(f"fedalign.{name}")
+
+
 def _train_steps(model, params, batch, lr, n_steps):
     """E local SGD steps on one client's batch (deterministic: full-batch
     gradients, no PRNG — re-running them reproduces the update exactly)."""
@@ -170,8 +191,10 @@ def _train_steps(model, params, batch, lr, n_steps):
 def _local_steps(model, params, batch, lr, n_steps):
     """Local training plus F_k(w_t) of the *received* model (the paper's
     matching statistic). Returns (params', loss0)."""
-    loss0, _ = model.loss_fn(params, batch)
-    return _train_steps(model, params, batch, lr, n_steps), loss0
+    with _phase("eval"):
+        loss0, _ = model.loss_fn(params, batch)
+    with _phase("train"):
+        return _train_steps(model, params, batch, lr, n_steps), loss0
 
 
 def _gate_ctx(fed, state, util_ema, local_losses, server_loss, pm, w,
@@ -271,6 +294,34 @@ def _failure_stats(fed, stats, lost, nonfinite_skips):
     return stats
 
 
+def _server_step(fed, state, agg_delta, mass, server_loss, local_losses,
+                 sel_gates, gates, util_ema, ef_accum, lost, pm, w):
+    """The round's last phase, shared by both pod rounds: the divergence
+    guard, the server optimizer (or the in-flight buffer), the next
+    FederationState and the round's stats. Returns (new_state, stats)."""
+    clock_on = fed.latency_mode != "none"
+    finite = engine.aggregate_finite(fed, agg_delta, server_loss)
+    push_timer = (engine.slot_timer(fed, state.latency, gates)
+                  if clock_on and fed.async_depth > 0 else None)
+    new_params, opt_state, inflight, last_delta, applied = _apply_delta(
+        fed, state, state.params, agg_delta, mass=mass,
+        push_timer=push_timer, finite=finite)
+    new_state = _next_state(fed, state, new_params, opt_state,
+                            sel_gates, gates, util_ema, inflight=inflight,
+                            last_delta=last_delta,
+                            nonfinite_skips=engine.skips_update(state, finite),
+                            ef_accum=ef_accum)
+    stats = _async_stats(fed, {
+        "server_loss": server_loss,
+        "local_losses": local_losses,
+        "gates": gates,
+        "backlog": new_state.backlog,
+        "theta_round": 1.0 / (1.0 + jnp.sum((1 - pm.astype(jnp.float32)) * w * gates)),
+    }, applied, inflight)
+    stats = _failure_stats(fed, stats, lost, new_state.nonfinite_skips)
+    return new_state, stats
+
+
 def pool_round_key(fed, round_idx):
     """The pod rounds take no rng argument, so the candidate-pool draw is a
     NAMED stream off the config seed folded with the ABSOLUTE round index —
@@ -304,40 +355,43 @@ def _pool_wrap(fed, round_step):
         C = pm.shape[0]
         if pool >= C:
             return round_step(state, batch, round_idx)
-        pool_idx = engine.pool_select(fed, pool_round_key(fed, round_idx),
-                                      pm, state.backlog, state.incl_ema,
-                                      pool)
+        with _phase("gate"):
+            pool_idx = engine.pool_select(fed, pool_round_key(fed, round_idx),
+                                          pm, state.backlog, state.incl_ema,
+                                          pool)
 
-        def take(a):
-            return a[pool_idx]
+            def take(a):
+                return a[pool_idx]
 
-        view = state.replace(
-            backlog=take(state.backlog),
-            util_ema=take(state.util_ema),
-            incl_ema=take(state.incl_ema),
-            latency=(jax.tree.map(take, state.latency) if clock_on
-                     else state.latency),
-            ef_accum=(jax.tree.map(take, state.ef_accum) if ef_on
-                      else state.ef_accum))
-        sub_batch = dict(batch)
-        sub_batch["clients"] = jax.tree.map(take, batch["clients"])
-        sub_batch["priority_mask"] = take(pm)
-        sub_batch["weights"] = take(batch["weights"])
+            view = state.replace(
+                backlog=take(state.backlog),
+                util_ema=take(state.util_ema),
+                incl_ema=take(state.incl_ema),
+                latency=(jax.tree.map(take, state.latency) if clock_on
+                         else state.latency),
+                ef_accum=(jax.tree.map(take, state.ef_accum) if ef_on
+                          else state.ef_accum))
+            sub_batch = dict(batch)
+            sub_batch["clients"] = jax.tree.map(take, batch["clients"])
+            sub_batch["priority_mask"] = take(pm)
+            sub_batch["weights"] = take(batch["weights"])
         sub, stats = round_step(view, sub_batch, round_idx,
                                 client_ids=pool_idx)
-        new_state = sub.replace(
-            backlog=state.backlog.at[pool_idx].set(sub.backlog),
-            util_ema=state.util_ema.at[pool_idx].set(sub.util_ema),
-            incl_ema=state.incl_ema.at[pool_idx].set(sub.incl_ema),
-            latency=state.latency,      # read-only: drawn once at init
-            ef_accum=(jax.tree.map(
-                lambda full, s: full.at[pool_idx].set(s),
-                state.ef_accum, sub.ef_accum) if ef_on else state.ef_accum))
-        # per-client stats keep the dense [C] index space downstream
-        # tooling expects; out-of-pool rows report 0
-        for name in ("local_losses", "gates"):
-            stats[name] = (jnp.zeros((C,), stats[name].dtype)
-                           .at[pool_idx].set(stats[name]))
+        with _phase("gate"):
+            new_state = sub.replace(
+                backlog=state.backlog.at[pool_idx].set(sub.backlog),
+                util_ema=state.util_ema.at[pool_idx].set(sub.util_ema),
+                incl_ema=state.incl_ema.at[pool_idx].set(sub.incl_ema),
+                latency=state.latency,      # read-only: drawn once at init
+                ef_accum=(jax.tree.map(
+                    lambda full, s: full.at[pool_idx].set(s),
+                    state.ef_accum, sub.ef_accum) if ef_on
+                    else state.ef_accum))
+            # per-client stats keep the dense [C] index space downstream
+            # tooling expects; out-of-pool rows report 0
+            for name in ("local_losses", "gates"):
+                stats[name] = (jnp.zeros((C,), stats[name].dtype)
+                               .at[pool_idx].set(stats[name]))
         stats["backlog"] = new_state.backlog
         stats["pool_idx"] = pool_idx
         return new_state, stats
@@ -365,7 +419,6 @@ def make_spatial_round(model, fed, num_clients: int):
     strategy = engine.get_strategy(fed.selection)
     use_cohort = fed.max_cohort > 0 and not strategy.needs_deltas
     failure_on = engine.resolve_failure_model(fed.failure_model) != "none"
-    clock_on = fed.latency_mode != "none"
     # the wire codec is shard-local: each pod shard encodes its own client
     # rows and the fused kernel decodes-and-reduces per shard — the single
     # cross-shard all-reduce stays on the [M_total] aggregate, unchanged
@@ -380,8 +433,8 @@ def make_spatial_round(model, fed, num_clients: int):
         w = batch["weights"]
         C = pm.shape[0]
 
-        server_loss, _ = model.loss_fn(params, batch["server"])
-        akey = aggregator_key(fed, round_idx) if agg_needs_key else None
+        with _phase("server_loss"):
+            server_loss, _ = model.loss_fn(params, batch["server"])
         ef_accum = state.ef_accum
 
         # fault injection mirrors the engine round: availability folds into
@@ -389,115 +442,110 @@ def make_spatial_round(model, fed, num_clients: int):
         # AFTER training (lost_mask), corruption rides the same transform.
         # client_ids (a pooled round's [P] global identities) keys the
         # fault draws on the IDENTITY, pool-independent
-        plan = (engine.failure_plan(fed, round_idx, C, client_ids=client_ids)
-                if failure_on else None)
-        part = (plan.available if plan is not None
-                and plan.available is not None else None)
-        lost = engine.lost_mask(fed, state, plan)
-        ctf = (engine.corruption_transform(fed, plan.corrupt)
-               if plan is not None and plan.corrupt is not None else None)
+        with _phase("gate"):
+            plan = (engine.failure_plan(fed, round_idx, C,
+                                        client_ids=client_ids)
+                    if failure_on else None)
+            part = (plan.available if plan is not None
+                    and plan.available is not None else None)
+            lost = engine.lost_mask(fed, state, plan)
+            ctf = (engine.corruption_transform(fed, plan.corrupt)
+                   if plan is not None and plan.corrupt is not None else None)
 
         if use_cohort:
             # eval -> gates -> gather-train: only K cohort slots pay E steps
-            local_losses = _client_vmap(
-                lambda cb: model.loss_fn(params, cb)[0])(client_batch)
-            util_ema = engine.utility_update(fed, state.util_ema,
-                                             local_losses, server_loss)
-            sel_gates = engine.compute_gates(
-                _gate_ctx(fed, state, util_ema, local_losses, server_loss,
-                          pm, w, round_idx=round_idx, participation=part),
-                fed.selection)
-            idx, cg, gates = engine.cohort_select(
-                sel_gates, local_losses, server_loss, pm,
-                min(fed.max_cohort, C), backlog=state.backlog,
-                backlog_boost=float(fed.backlog_boost))
-            cohort_params = _client_vmap(
-                lambda cb: _train_steps(model, params, cb, lr, E))(
-                jax.tree.map(lambda a: a[idx], client_batch))
-            if ctf is not None:
-                cohort_params = ctf(cohort_params, params, idx)
-            agg_w, agg_g = w[idx], cg
-            if lost is not None:
-                # crashed / deadline-late: trained, but the delta never
-                # arrives — mass masked out; sel_gates stay, so the backlog
-                # re-enqueues them (+1, tie-winning on return)
-                keep = 1.0 - lost.astype(jnp.float32)
-                agg_g = agg_g * keep[idx]
-                gates = gates * keep
-            if ef_on:
-                # only the K gathered slots encoded a delta: their EF rows
-                # gather with the cohort, scatter back advanced
-                cohort_ef = jax.tree.map(lambda a: a[idx], state.ef_accum)
-                agg_delta, cohort_ef = engine.server_delta(
-                    fed, params, cohort_params, agg_w, agg_g, key=akey,
-                    ef_accum=cohort_ef)
-                ef_accum = jax.tree.map(
-                    lambda full, sub: full.at[idx].set(sub),
-                    state.ef_accum, cohort_ef)
-            else:
-                agg_delta = engine.server_delta(fed, params, cohort_params,
-                                                agg_w, agg_g, key=akey)
+            with _phase("eval"):
+                local_losses = _client_vmap(
+                    lambda cb: model.loss_fn(params, cb)[0])(client_batch)
+            with _phase("gate"):
+                util_ema = engine.utility_update(fed, state.util_ema,
+                                                 local_losses, server_loss)
+                sel_gates = engine.compute_gates(
+                    _gate_ctx(fed, state, util_ema, local_losses, server_loss,
+                              pm, w, round_idx=round_idx, participation=part),
+                    fed.selection)
+                idx, cg, gates = engine.cohort_select(
+                    sel_gates, local_losses, server_loss, pm,
+                    min(fed.max_cohort, C), backlog=state.backlog,
+                    backlog_boost=float(fed.backlog_boost))
+                cohort_batch = jax.tree.map(lambda a: a[idx], client_batch)
+            with _phase("train"):
+                cohort_params = _client_vmap(
+                    lambda cb: _train_steps(model, params, cb, lr, E))(
+                    cohort_batch)
+            with _phase("gate"):
+                if ctf is not None:
+                    cohort_params = ctf(cohort_params, params, idx)
+                agg_w, agg_g = w[idx], cg
+                if lost is not None:
+                    # crashed / deadline-late: trained, but the delta never
+                    # arrives — mass masked out; sel_gates stay, so the
+                    # backlog re-enqueues them (+1, tie-winning on return)
+                    keep = 1.0 - lost.astype(jnp.float32)
+                    agg_g = agg_g * keep[idx]
+                    gates = gates * keep
+            with _phase("aggregate"):
+                akey = aggregator_key(fed, round_idx) if agg_needs_key else None
+                if ef_on:
+                    # only the K gathered slots encoded a delta: their EF
+                    # rows gather with the cohort, scatter back advanced
+                    cohort_ef = jax.tree.map(lambda a: a[idx], state.ef_accum)
+                    agg_delta, cohort_ef = engine.server_delta(
+                        fed, params, cohort_params, agg_w, agg_g, key=akey,
+                        ef_accum=cohort_ef)
+                    ef_accum = jax.tree.map(
+                        lambda full, sub: full.at[idx].set(sub),
+                        state.ef_accum, cohort_ef)
+                else:
+                    agg_delta = engine.server_delta(fed, params, cohort_params,
+                                                    agg_w, agg_g, key=akey)
         else:
             client_params, local_losses = _client_vmap(
                 lambda cb: _local_steps(model, params, cb, lr, E))(client_batch)
-            util_ema = engine.utility_update(fed, state.util_ema,
-                                             local_losses, server_loss)
-            if ctf is not None:
-                # before the delta statistic, matching the engine: a
-                # realistic attacker influences grad_sim scores with the
-                # very delta it submits
-                client_params = ctf(client_params, params, jnp.arange(C))
+            with _phase("gate"):
+                util_ema = engine.utility_update(fed, state.util_ema,
+                                                 local_losses, server_loss)
+                if ctf is not None:
+                    # before the delta statistic, matching the engine: a
+                    # realistic attacker influences grad_sim scores with the
+                    # very delta it submits
+                    client_params = ctf(client_params, params, jnp.arange(C))
 
-            delta_cos = None
-            if strategy.needs_deltas:
-                deltas = jax.tree.map(lambda ck, g: ck - g[None],
-                                      client_params, params)
-                if fed.grad_sim_sketch:
-                    skey = engine.sketch_key(fed, round_idx)
-                    sketches = jax.vmap(lambda d: engine.delta_sketch(
-                        d, skey, int(fed.sketch_dim)))(deltas)
-                    delta_cos = engine.cosine_to_priority(sketches, w, pm)
-                else:
-                    delta_cos = engine.cosine_to_priority(
-                        flatten_stacked(deltas), w, pm)
+                delta_cos = None
+                if strategy.needs_deltas:
+                    deltas = jax.tree.map(lambda ck, g: ck - g[None],
+                                          client_params, params)
+                    if fed.grad_sim_sketch:
+                        skey = engine.sketch_key(fed, round_idx)
+                        sketches = jax.vmap(lambda d: engine.delta_sketch(
+                            d, skey, int(fed.sketch_dim)))(deltas)
+                        delta_cos = engine.cosine_to_priority(sketches, w, pm)
+                    else:
+                        delta_cos = engine.cosine_to_priority(
+                            flatten_stacked(deltas), w, pm)
 
-            sel_gates = gates = engine.compute_gates(
-                _gate_ctx(fed, state, util_ema, local_losses, server_loss,
-                          pm, w, delta_cos, round_idx=round_idx,
-                          participation=part),
-                fed.selection)
-            if lost is not None:
-                gates = gates * (1.0 - lost.astype(jnp.float32))
+                sel_gates = gates = engine.compute_gates(
+                    _gate_ctx(fed, state, util_ema, local_losses, server_loss,
+                              pm, w, delta_cos, round_idx=round_idx,
+                              participation=part),
+                    fed.selection)
+                if lost is not None:
+                    gates = gates * (1.0 - lost.astype(jnp.float32))
             agg_w, agg_g = w, gates
-            if ef_on:
-                agg_delta, ef_accum = engine.server_delta(
-                    fed, params, client_params, agg_w, agg_g, key=akey,
-                    ef_accum=state.ef_accum)
-            else:
-                agg_delta = engine.server_delta(fed, params, client_params,
-                                                agg_w, agg_g, key=akey)
-        finite = engine.aggregate_finite(fed, agg_delta, server_loss)
-        push_timer = (engine.slot_timer(fed, state.latency, gates)
-                      if clock_on and fed.async_depth > 0 else None)
-        new_params, opt_state, inflight, last_delta, applied = _apply_delta(
-            fed, state, params, agg_delta,
-            mass=inclusion_mass(fed, agg_w, agg_g),
-            push_timer=push_timer, finite=finite)
-        new_state = _next_state(fed, state, new_params, opt_state,
-                                sel_gates, gates, util_ema, inflight=inflight,
-                                last_delta=last_delta,
-                                nonfinite_skips=engine.skips_update(state,
-                                                                    finite),
-                                ef_accum=ef_accum)
-        stats = _async_stats(fed, {
-            "server_loss": server_loss,
-            "local_losses": local_losses,
-            "gates": gates,
-            "backlog": new_state.backlog,
-            "theta_round": 1.0 / (1.0 + jnp.sum((1 - pm.astype(jnp.float32)) * w * gates)),
-        }, applied, inflight)
-        stats = _failure_stats(fed, stats, lost, new_state.nonfinite_skips)
-        return new_state, stats
+            with _phase("aggregate"):
+                akey = aggregator_key(fed, round_idx) if agg_needs_key else None
+                if ef_on:
+                    agg_delta, ef_accum = engine.server_delta(
+                        fed, params, client_params, agg_w, agg_g, key=akey,
+                        ef_accum=state.ef_accum)
+                else:
+                    agg_delta = engine.server_delta(fed, params, client_params,
+                                                    agg_w, agg_g, key=akey)
+        with _phase("server_step"):
+            return _server_step(fed, state, agg_delta,
+                                inclusion_mass(fed, agg_w, agg_g),
+                                server_loss, local_losses, sel_gates, gates,
+                                util_ema, ef_accum, lost, pm, w)
 
     return _kernels_per_shard(_pool_wrap(fed, round_step))
 
@@ -523,9 +571,9 @@ def make_temporal_round(model, fed, cohort: int):
     clients, dp clips on whole-delta norms, and cosine_filter compares
     client directions — none decompose into a running sum. With
     ``fed.aggregator != "mean"`` the scan therefore stacks every client's
-    trained params as its ys output — a deliberate resharding that
-    materializes [C, ...] leaves (asserted below), the one place the
-    temporal round pays spatial-round memory — and routes them through
+    trained params into a [C, ...] carry — a deliberate resharding, the
+    one place the temporal round pays spatial-round memory — and routes
+    them through
     ``engine.server_delta`` (the same fused fedagg call as the spatial
     round, so the two pod modes stay bit-comparable per aggregator).
     """
@@ -543,7 +591,6 @@ def make_temporal_round(model, fed, cohort: int):
     agg_needs_key = get_aggregator(fed.aggregator).needs_key
     strategy = engine.get_strategy(fed.selection)
     failure_on = engine.resolve_failure_model(fed.failure_model) != "none"
-    clock_on = fed.latency_mode != "none"
     if (engine.resolve_failure_model(fed.failure_model) in ("corrupt", "chaos")
             and fed.corrupt_rate > 0):
         raise ValueError(
@@ -567,25 +614,32 @@ def make_temporal_round(model, fed, cohort: int):
         pm = batch["priority_mask"]
         w = batch["weights"]
         C = pm.shape[0]
-        server_loss, _ = model.loss_fn(params, batch["server"])
+        with _phase("server_loss"):
+            server_loss, _ = model.loss_fn(params, batch["server"])
         ef_accum = state.ef_accum
 
         # fault injection (corruption excluded above): availability masks
         # selection, crashes/deadline-late clients lose their mass
         # post-train; client_ids keys pooled draws on the global identity
-        plan = (engine.failure_plan(fed, round_idx, C, client_ids=client_ids)
-                if failure_on else None)
-        part = (plan.available if plan is not None
-                and plan.available is not None else None)
-        lost = engine.lost_mask(fed, state, plan)
+        with _phase("gate"):
+            plan = (engine.failure_plan(fed, round_idx, C,
+                                        client_ids=client_ids)
+                    if failure_on else None)
+            part = (plan.available if plan is not None
+                    and plan.available is not None else None)
+            lost = engine.lost_mask(fed, state, plan)
 
         # eval pre-pass: F_k(w_t) for the whole cohort before any gate is
         # fixed (rank-based strategies need the full loss vector)
-        local_losses = jax.lax.map(
-            lambda cb: model.loss_fn(params, cb)[0], batch["clients"])
-        util_ema = engine.utility_update(fed, state.util_ema,
-                                         local_losses, server_loss)
+        with _phase("eval"):
+            local_losses = jax.lax.map(
+                lambda cb: model.loss_fn(params, cb)[0], batch["clients"])
+        with _phase("gate"):
+            util_ema = engine.utility_update(fed, state.util_ema,
+                                             local_losses, server_loss)
 
+        # a scan whose body holds several phases is called outside any
+        # phase: its body scopes each of them
         delta_cos = None
         if strategy.needs_deltas:
             # pass 1: train each streamed client, keep only its delta sketch
@@ -593,70 +647,82 @@ def make_temporal_round(model, fed, cohort: int):
             dim = int(fed.sketch_dim)
 
             def sketch_client(carry, cbatch):
-                p_k = _train_steps(model, params, cbatch, lr, E)
-                return carry, engine.delta_sketch(tree_sub(p_k, params),
-                                                  skey, dim)
+                with _phase("train"):
+                    p_k = _train_steps(model, params, cbatch, lr, E)
+                with _phase("gate"):
+                    return carry, engine.delta_sketch(tree_sub(p_k, params),
+                                                      skey, dim)
 
             _, sketches = jax.lax.scan(sketch_client, 0, batch["clients"])
-            delta_cos = engine.cosine_to_priority(sketches, w, pm)
+            with _phase("gate"):
+                delta_cos = engine.cosine_to_priority(sketches, w, pm)
 
-        sel_gates = gates = engine.compute_gates(
-            _gate_ctx(fed, state, util_ema, local_losses, server_loss, pm, w,
-                      delta_cos, round_idx=round_idx, participation=part),
-            fed.selection)
-        if lost is not None:
-            # a lost streamed client's delta never reaches the carry, so it
-            # may as well skip its E local steps (gate 0 cond-skips); its
-            # SELECTION gate stays for the backlog re-enqueue
-            gates = gates * (1.0 - lost.astype(jnp.float32))
+        with _phase("gate"):
+            sel_gates = gates = engine.compute_gates(
+                _gate_ctx(fed, state, util_ema, local_losses, server_loss,
+                          pm, w, delta_cos, round_idx=round_idx,
+                          participation=part),
+                fed.selection)
+            if lost is not None:
+                # a lost streamed client's delta never reaches the carry, so
+                # it may as well skip its E local steps (gate 0 cond-skips);
+                # its SELECTION gate stays for the backlog re-enqueue
+                gates = gates * (1.0 - lost.astype(jnp.float32))
+
+        def train_if_gated(cbatch, gate):
+            # gates are fixed before the scan, so gated-out streamed clients
+            # skip their E local steps entirely (cond, not select: scan
+            # bodies are traced once and branch at run time)
+            with _phase("train"):
+                return jax.lax.cond(
+                    gate > 0,
+                    lambda b: _train_steps(model, params, b, lr, E),
+                    lambda b: params, cbatch)
 
         if robust_gather:
             # robust/private aggregators need every client's delta at once
             # (order statistics / whole-delta norms / direction cosines):
-            # stack the trained params as scan ys — the documented [C, ...]
-            # resharding — and reduce through THE fused fedagg seam.
-            def per_client_stack(carry, inp):
-                cbatch, gate = inp
-                p_k = jax.lax.cond(
-                    gate > 0,
-                    lambda b: _train_steps(model, params, b, lr, E),
-                    lambda b: params, cbatch)
-                return carry, p_k
+            # stack the trained params into a [C, ...] carry — the
+            # documented resharding — and reduce through THE fused fedagg
+            # seam.
+            def per_client_stack(stacked, inp):
+                k, cbatch, gate = inp
+                p_k = train_if_gated(cbatch, gate)
+                with _phase("aggregate"):
+                    return jax.tree.map(
+                        lambda s, p: jax.lax.dynamic_update_index_in_dim(
+                            s, p, k, 0), stacked, p_k), None
 
-            _, stacked = jax.lax.scan(per_client_stack, 0,
-                                      (batch["clients"], gates))
-            C = w.shape[0]
-            for s, p in zip(jax.tree.leaves(stacked), jax.tree.leaves(params)):
-                assert s.shape == (C,) + p.shape, (
-                    "temporal robust aggregation must gather the client axis: "
-                    f"expected {(C,) + p.shape}, got {s.shape}")
-            akey = aggregator_key(fed, round_idx) if agg_needs_key else None
-            if ef_on:
-                agg_delta, ef_accum = engine.server_delta(
-                    fed, params, stacked, w, gates, key=akey,
-                    ef_accum=state.ef_accum)
-            else:
-                agg_delta = engine.server_delta(fed, params, stacked, w,
-                                                gates, key=akey)
-            mass = inclusion_mass(fed, w, gates)
+            with _phase("aggregate"):
+                empty = jax.tree.map(
+                    lambda p: jnp.zeros((C,) + p.shape, p.dtype), params)
+            stacked, _ = jax.lax.scan(per_client_stack, empty,
+                                      (jnp.arange(C), batch["clients"], gates))
+            with _phase("aggregate"):
+                akey = aggregator_key(fed, round_idx) if agg_needs_key else None
+                if ef_on:
+                    agg_delta, ef_accum = engine.server_delta(
+                        fed, params, stacked, w, gates, key=akey,
+                        ef_accum=state.ef_accum)
+                else:
+                    agg_delta = engine.server_delta(fed, params, stacked, w,
+                                                    gates, key=akey)
+                mass = inclusion_mass(fed, w, gates)
         else:
             def per_client(carry, inp):
                 acc_num, acc_den = carry
                 cbatch, w_k, gate = inp
-                # gates are fixed before the scan, so gated-out streamed
-                # clients skip their E local steps entirely (cond, not
-                # select: scan bodies are traced once and branch at run time)
-                p_k = jax.lax.cond(
-                    gate > 0,
-                    lambda b: _train_steps(model, params, b, lr, E),
-                    lambda b: params, cbatch)
-                wg = w_k * gate
-                acc_num = jax.tree.map(
-                    lambda a, pk: a + wg * pk.astype(jnp.float32), acc_num, p_k)
-                return (acc_num, acc_den + wg), None
+                p_k = train_if_gated(cbatch, gate)
+                with _phase("aggregate"):
+                    wg = w_k * gate
+                    acc_num = jax.tree.map(
+                        lambda a, pk: a + wg * pk.astype(jnp.float32),
+                        acc_num, p_k)
+                    return (acc_num, acc_den + wg), None
 
-            zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
-                                 params)
+            with _phase("aggregate"):
+                zeros = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
             (num, den), _ = jax.lax.scan(
                 per_client, (zeros, jnp.float32(0)),
                 (batch["clients"], w, gates))
@@ -666,32 +732,17 @@ def make_temporal_round(model, fed, cohort: int):
             # overlapped cohorts). A zero-mass round yields an EXACT zero
             # delta (num/1e-30 - params would be -params, wiping the model).
             mass = den
-            agg_delta = jax.tree.map(
-                lambda n, p: jnp.where(
-                    den > 0,
-                    n / jnp.maximum(den, 1e-30) - p.astype(jnp.float32), 0.0),
-                num, params)
-        finite = engine.aggregate_finite(fed, agg_delta, server_loss)
-        push_timer = (engine.slot_timer(fed, state.latency, gates)
-                      if clock_on and fed.async_depth > 0 else None)
-        new_params, opt_state, inflight, last_delta, applied = _apply_delta(
-            fed, state, params, agg_delta, mass=mass,
-            push_timer=push_timer, finite=finite)
-        new_state = _next_state(fed, state, new_params, opt_state,
-                                sel_gates, gates, util_ema, inflight=inflight,
-                                last_delta=last_delta,
-                                nonfinite_skips=engine.skips_update(state,
-                                                                    finite),
-                                ef_accum=ef_accum)
-        stats = _async_stats(fed, {
-            "server_loss": server_loss,
-            "local_losses": local_losses,
-            "gates": gates,
-            "backlog": new_state.backlog,
-            "theta_round": 1.0 / (1.0 + jnp.sum((1 - pm.astype(jnp.float32)) * w * gates)),
-        }, applied, inflight)
-        stats = _failure_stats(fed, stats, lost, new_state.nonfinite_skips)
-        return new_state, stats
+            with _phase("aggregate"):
+                agg_delta = jax.tree.map(
+                    lambda n, p: jnp.where(
+                        den > 0,
+                        n / jnp.maximum(den, 1e-30) - p.astype(jnp.float32),
+                        0.0),
+                    num, params)
+        with _phase("server_step"):
+            return _server_step(fed, state, agg_delta, mass, server_loss,
+                                local_losses, sel_gates, gates, util_ema,
+                                ef_accum, lost, pm, w)
 
     return _kernels_per_shard(_pool_wrap(fed, round_step))
 

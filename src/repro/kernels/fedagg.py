@@ -294,12 +294,14 @@ def fedagg_pallas(updates, weights, gates, *, block_m=2048, interpret=False,
     else:
         raise ValueError(f"unknown in-kernel aggregator {aggregator!r}")
 
-    out = pl.pallas_call(
-        kernel,
-        grid=(nm,),
-        in_specs=in_specs,
-        out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct((1, M), out_dtype),
-        interpret=interpret,
-    )(*operands)
+    with jax.named_scope("kernel.fedagg"):
+        out = pl.pallas_call(
+            kernel,
+            name="kernel.fedagg",
+            grid=(nm,),
+            in_specs=in_specs,
+            out_specs=row_spec,
+            out_shape=jax.ShapeDtypeStruct((1, M), out_dtype),
+            interpret=interpret,
+        )(*operands)
     return out.reshape(M)

@@ -203,29 +203,31 @@ def flash_attention_fwd_pallas(q, k, v, *, causal=True, window=0, scale=None,
         _kernel_fwd_lse, causal=causal, window=window, scale=scale,
         block_q=block_q, block_kv=block_kv, nkv=nkv, q_offset=Skv - Sq)
 
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B * KV, G, nq, nkv),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, g, iq, ik: (b, g, iq, 0)),
-            pl.BlockSpec((1, block_kv, hd), lambda b, g, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, block_kv, hd), lambda b, g, iq, ik: (b, ik, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, g, iq, ik: (b, g, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, g, iq, ik: (b, g, iq)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * KV, G, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B * KV, G, Sq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qr, kr, vr)
+    with jax.named_scope("kernel.flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            name="kernel.flash_fwd",
+            grid=(B * KV, G, nq, nkv),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, hd), lambda b, g, iq, ik: (b, g, iq, 0)),
+                pl.BlockSpec((1, block_kv, hd), lambda b, g, iq, ik: (b, ik, 0)),
+                pl.BlockSpec((1, block_kv, hd), lambda b, g, iq, ik: (b, ik, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, hd), lambda b, g, iq, ik: (b, g, iq, 0)),
+                pl.BlockSpec((1, 1, block_q), lambda b, g, iq, ik: (b, g, iq)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B * KV, G, Sq, hd), q.dtype),
+                jax.ShapeDtypeStruct((B * KV, G, Sq), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q, hd), jnp.float32),
+            ],
+            interpret=interpret,
+        )(qr, kr, vr)
     return _unlayout_q(out, dims), lse
 
 
@@ -253,15 +255,18 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, *, causal=True, window=0,
     kv_spec_q = pl.BlockSpec((1, block_kv, hd), lambda b, g, i, j: (b, j, 0))
     row_spec = pl.BlockSpec((1, 1, block_q), lambda b, g, i, j: (b, g, i))
 
-    dq = pl.pallas_call(
-        functools.partial(_kernel_dq, nkv=nkv, **common),
-        grid=(B * KV, G, nq, nkv),
-        in_specs=[q_spec, kv_spec_q, kv_spec_q, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B * KV, G, Sq, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        interpret=interpret,
-    )(qr, kr, vr, dor, lse, delta)
+    with jax.named_scope("kernel.flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_kernel_dq, nkv=nkv, **common),
+            name="kernel.flash_bwd_dq",
+            grid=(B * KV, G, nq, nkv),
+            in_specs=[q_spec, kv_spec_q, kv_spec_q, q_spec, row_spec,
+                      row_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((B * KV, G, Sq, hd), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
+            interpret=interpret,
+        )(qr, kr, vr, dor, lse, delta)
 
     # dk/dv: kv block outer, q block inner (sequential) so dk/dv accumulate
     q_spec2 = pl.BlockSpec((1, 1, block_q, hd), lambda b, g, j, i: (b, g, i, 0))
@@ -273,18 +278,21 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, *, causal=True, window=0,
     # scratch — run one call per group and sum (G is small: <= 8 for the
     # assigned archs). G==1 (MHA after grouping) needs a single call.
     def _dkv_call(qg, dog, lseg, deltag):
-        return pl.pallas_call(
-            functools.partial(_kernel_dkv, nq=nq, **common),
-            grid=(B * KV, 1, nkv, nq),
-            in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2,
-                      row_spec2],
-            out_specs=[kv_spec2, kv_spec2],
-            out_shape=[jax.ShapeDtypeStruct((B * KV, Skv, hd), jnp.float32),
-                       jax.ShapeDtypeStruct((B * KV, Skv, hd), jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((block_kv, hd), jnp.float32),
-                            pltpu.VMEM((block_kv, hd), jnp.float32)],
-            interpret=interpret,
-        )(qg, kr, vr, dog, lseg, deltag)
+        with jax.named_scope("kernel.flash_bwd_dkv"):
+            return pl.pallas_call(
+                functools.partial(_kernel_dkv, nq=nq, **common),
+                name="kernel.flash_bwd_dkv",
+                grid=(B * KV, 1, nkv, nq),
+                in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2,
+                          row_spec2],
+                out_specs=[kv_spec2, kv_spec2],
+                out_shape=[
+                    jax.ShapeDtypeStruct((B * KV, Skv, hd), jnp.float32),
+                    jax.ShapeDtypeStruct((B * KV, Skv, hd), jnp.float32)],
+                scratch_shapes=[pltpu.VMEM((block_kv, hd), jnp.float32),
+                                pltpu.VMEM((block_kv, hd), jnp.float32)],
+                interpret=interpret,
+            )(qg, kr, vr, dog, lseg, deltag)
 
     dk_g = jnp.zeros((B * KV, Skv, hd), jnp.float32)
     dv_g = jnp.zeros((B * KV, Skv, hd), jnp.float32)

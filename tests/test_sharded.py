@@ -243,6 +243,35 @@ def test_sharded_cohort_select_is_engine_cohort_select():
     assert "argsort" not in src and "lexsort" not in src
 
 
+PHASE_VARIANTS = {
+    "spatial_dense": (False, {}),
+    "spatial_max_cohort": (False, {"max_cohort": 3}),
+    "temporal_mean": (True, {}),
+    "temporal_trimmed_mean": (True, {"aggregator": "trimmed_mean"}),
+    "candidate_pool": (False, {"candidate_pool": 3}),
+    "async_depth_1": (True, {"async_depth": 1, "backend": "scan_async"}),
+}
+
+
+@pytest.mark.parametrize("variant", list(PHASE_VARIANTS))
+def test_round_phases_name_the_compiled_program(variant):
+    """Every phase the round runs names some instruction of its compiled
+    program (``fedalign.<phase>`` in the op_name metadata), and no
+    instruction falls under two phases."""
+    import re
+    fsdp, knobs = PHASE_VARIANTS[variant]
+    fed = FED.replace(epsilon=0.5, **knobs)
+    step = jax.jit(sharded.make_round_step(MODEL, fed, 4, fsdp=fsdp))
+    text = step.lower(_state(fed), _batch(), jnp.int32(0)).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    seen = set()
+    for name in op_names:
+        phases = re.findall(r"fedalign\.(\w+)", name)
+        assert len(phases) <= 1, name
+        seen.update(phases)
+    assert seen == set(sharded.PHASES)
+
+
 def test_train_driver_end_to_end():
     params, hist = train_run(arch="qwen1.5-0.5b", smoke=True, rounds=3,
                              clients=4, n_priority=2, per_client=2, seq=32,
