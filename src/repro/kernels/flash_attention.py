@@ -12,6 +12,19 @@ Layout notes (HBM->VMEM):
 hd is expected to be 64/96/128 (lane-aligned); block_q/block_kv multiples
 of 128 keep the MXU fed on the s = q @ k^T and p @ v contractions.
 
+Forward tiling (``fwd_tile_plan``): each grid step has a fixed cost, so the
+forward takes the largest of 512/256/128 that divides each length (a
+length up to 128 is one tile); 512 caps Mosaic's compile time. A tile the
+mask empties in full (keys after every query under ``causal``, or all
+``window`` or more behind) does no work, and its kv block index is clamped
+to the nearest block its row of tiles runs, so the pipeline copies nothing
+for it: at S=1024 causal, 3 of 4 tiles of 512 run. The running max ``m``
+and sum ``l`` are [block_q, 128] scratch, replicated across lanes, so the
+per-tile row reductions broadcast back without a lane/sublane relayout;
+the output divide and the LSE write happen once, on the row's last tile
+that runs. The backward keeps 128 tiles and reads the LSE as
+[B*KV, G, Sq] f32.
+
 Forward and backward compile for TPU v5e as written
 (``tests/test_tpu_compile.py``) and match ``ref.attention_ref`` on the
 chip to bf16 rounding (``chip_smoke.py``).
@@ -22,68 +35,63 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128            # the online-softmax state's lane width
+FWD_BLOCKS = (512, 256, 128)
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            causal, window, scale, block_q, block_kv, nkv, q_offset):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+def _fwd_block(n):
+    """The forward's tile along a sequence of length n: n itself up to 128,
+    else the largest of FWD_BLOCKS that divides it (the kernel's domain is
+    lengths up to 128 or multiples of 128)."""
+    if n <= LANES:
+        return n
+    return next((b for b in FWD_BLOCKS if n % b == 0), LANES)
 
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale            # [bq, hd]
-    k = k_ref[0].astype(jnp.float32)                       # [bk, hd]
-    v = v_ref[0].astype(jnp.float32)                       # [bk, hd]
+def fwd_tile_plan(Sq, Skv, *, causal=True, window=0, block_q=None,
+                  block_kv=None):
+    """The forward's tiles and how many of them run, per (batch, head):
+    (block_q, block_kv, tiles_run, tiles_total). Tiles come from the
+    lengths unless given; a tile the mask empties in full is skipped."""
+    block_q = _fwd_block(Sq) if block_q is None else min(block_q, Sq)
+    block_kv = _fwd_block(Skv) if block_kv is None else min(block_kv, Skv)
+    nq, nkv = Sq // block_q, Skv // block_kv
+    run = 0
+    for iq in range(nq):
+        first, last = _kv_span(iq, nkv, block_q=block_q, block_kv=block_kv,
+                               causal=causal, window=window,
+                               q_offset=Skv - Sq, xp=np)
+        run += int(last) - int(first) + 1
+    return block_q, block_kv, run, nq * nkv
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [bq, bk]
 
-    q_pos = q_offset + iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
-    k_pos = ik * block_kv + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
-    mask = jnp.ones((block_q, block_kv), jnp.bool_)
-    if causal:
-        mask = mask & (k_pos <= q_pos)
+def _kv_span(iq, nkv, *, block_q, block_kv, causal, window, q_offset,
+             xp=jnp):
+    """First and last kv block that row ``iq`` of q tiles needs: every tile
+    outside [first, last] is masked in full (keys after the row's last
+    query under ``causal``, keys ``window`` or more behind its first query).
+    Scalar arithmetic on a program id (``xp=jnp``) or on ints (``xp=np``)."""
+    first, last = 0, nkv - 1
     if window:
-        mask = mask & (k_pos > q_pos - window)
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_new = l_prev * corr + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-
-    @pl.when(ik == nkv - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        first = xp.maximum(q_offset + iq * block_q - window + 1, 0) // block_kv
+    if causal:
+        last = xp.minimum(last, (q_offset + (iq + 1) * block_q - 1) // block_kv)
+    return first, last
 
 
-def _kernel_fwd_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                    causal, window, scale, block_q, block_kv, nkv, q_offset):
-    """Forward kernel variant that also emits LSE = m + log(l) per query row
-    (needed by the backward pass)."""
-    ik = pl.program_id(3)
-    _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            causal=causal, window=window, scale=scale, block_q=block_q,
-            block_kv=block_kv, nkv=nkv, q_offset=q_offset)
-
-    @pl.when(ik == nkv - 1)
-    def _emit_lse():
-        lse_ref[0, 0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
+def _lanes(x, n):
+    """A lane-replicated [rows, 128] state as [rows, n]: whole vregs
+    repeated, or lanes cut, so no row vector changes layout."""
+    if n % LANES == 0:
+        return jnp.tile(x, (1, n // LANES))
+    if n < LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
 def _mask(block_q, block_kv, iq, ik, *, causal, window, q_offset):
@@ -97,6 +105,50 @@ def _mask(block_q, block_kv, iq, ik, *, causal, window, q_offset):
     if window:
         mask = mask & (k_pos > q_pos - window)
     return mask
+
+
+def _kernel_fwd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+                causal, window, scale, block_q, block_kv, nkv, q_offset):
+    """Online-softmax forward over the kv tiles of one q tile; emits the
+    output and LSE = m + log(l) per query row (read by the backward)."""
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+    first, last = _kv_span(iq, nkv, block_q=block_q, block_kv=block_kv,
+                           causal=causal, window=window, q_offset=q_offset)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((ik >= first) & (ik <= last))
+    def _step():
+        q = q_ref[0, 0].astype(jnp.float32) * scale            # [bq, hd]
+        k = k_ref[0].astype(jnp.float32)                       # [bk, hd]
+        v = v_ref[0].astype(jnp.float32)                       # [bk, hd]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # [bq, bk]
+        mask = _mask(block_q, block_kv, iq, ik, causal=causal, window=window,
+                     q_offset=q_offset)
+        s = jnp.where(mask, s, NEG_INF)
+
+        m_prev = m_ref[...]                                    # [bq, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - _lanes(m_new, block_kv))
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * _lanes(corr, acc_ref.shape[1]) + \
+            jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(ik == last)
+    def _done():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / _lanes(l, acc_ref.shape[1])
+                       ).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
 
 
 def _kernel_dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *,
@@ -188,20 +240,29 @@ def _unlayout_q(x, dims):
 
 
 def flash_attention_fwd_pallas(q, k, v, *, causal=True, window=0, scale=None,
-                               block_q=128, block_kv=128, interpret=False):
-    """Returns (out [B,Sq,H,hd], lse [B*KV, G, Sq])."""
+                               block_q=None, block_kv=None, interpret=False):
+    """Returns (out [B,Sq,H,hd], lse [B*KV, G, Sq]). Tiles from
+    ``fwd_tile_plan`` unless given."""
     qr, kr, vr, dims = _layout(q, k, v)
     B, Sq, H, hd, Skv, KV, G = dims
     if scale is None:
         scale = hd ** -0.5
-    block_q = min(block_q, Sq)
-    block_kv = min(block_kv, Skv)
+    assert not causal or Sq <= Skv, "causal queries need keys up to them"
+    block_q, block_kv, _, _ = fwd_tile_plan(Sq, Skv, causal=causal,
+                                            window=window, block_q=block_q,
+                                            block_kv=block_kv)
     assert Sq % block_q == 0 and Skv % block_kv == 0
     nq, nkv = Sq // block_q, Skv // block_kv
+    tiles = dict(causal=causal, window=window, block_q=block_q,
+                 block_kv=block_kv, nkv=nkv, q_offset=Skv - Sq)
+    span = functools.partial(_kv_span, **tiles)
 
-    kernel = functools.partial(
-        _kernel_fwd_lse, causal=causal, window=window, scale=scale,
-        block_q=block_q, block_kv=block_kv, nkv=nkv, q_offset=Skv - Sq)
+    def kv_map(b, g, iq, ik):
+        # a skipped tile maps to the nearest block its row runs: no copy
+        first, last = span(iq)
+        return (b, jnp.minimum(jnp.maximum(ik, first), last), 0)
+
+    kernel = functools.partial(_kernel_fwd, scale=scale, **tiles)
 
     with jax.named_scope("kernel.flash_fwd"):
         out, lse = pl.pallas_call(
@@ -210,8 +271,8 @@ def flash_attention_fwd_pallas(q, k, v, *, causal=True, window=0, scale=None,
             grid=(B * KV, G, nq, nkv),
             in_specs=[
                 pl.BlockSpec((1, 1, block_q, hd), lambda b, g, iq, ik: (b, g, iq, 0)),
-                pl.BlockSpec((1, block_kv, hd), lambda b, g, iq, ik: (b, ik, 0)),
-                pl.BlockSpec((1, block_kv, hd), lambda b, g, iq, ik: (b, ik, 0)),
+                pl.BlockSpec((1, block_kv, hd), kv_map),
+                pl.BlockSpec((1, block_kv, hd), kv_map),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, block_q, hd), lambda b, g, iq, ik: (b, g, iq, 0)),
@@ -222,8 +283,8 @@ def flash_attention_fwd_pallas(q, k, v, *, causal=True, window=0, scale=None,
                 jax.ShapeDtypeStruct((B * KV, G, Sq), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q,), jnp.float32),
-                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
                 pltpu.VMEM((block_q, hd), jnp.float32),
             ],
             interpret=interpret,
@@ -309,31 +370,32 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, *, causal=True, window=0,
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=0, kv_len=None,
-                           scale=None, block_q=128, block_kv=128, interpret=False):
+                           scale=None, block_q=None, block_kv=None,
+                           interpret=False):
     """q: [B,Sq,H,hd]; k/v: [B,Skv,KV,hd]. Returns [B,Sq,H,hd].
 
     Differentiable: forward saves per-row LSE; backward runs the two-pass
-    flash backward kernels (dq then dk/dv)."""
+    flash backward kernels (dq then dk/dv). Given tiles serve both passes;
+    by default the forward takes ``fwd_tile_plan``'s and the backward 128."""
     assert kv_len is None, "flash path assumes a full kv sequence"
+    fwd = functools.partial(
+        flash_attention_fwd_pallas, causal=causal, window=window, scale=scale,
+        block_q=block_q, block_kv=block_kv, interpret=interpret)
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=())
     def _fa(q, k, v):
-        out, _ = flash_attention_fwd_pallas(
-            q, k, v, causal=causal, window=window, scale=scale,
-            block_q=block_q, block_kv=block_kv, interpret=interpret)
-        return out
+        return fwd(q, k, v)[0]
 
     def _fwd(q, k, v):
-        out, lse = flash_attention_fwd_pallas(
-            q, k, v, causal=causal, window=window, scale=scale,
-            block_q=block_q, block_kv=block_kv, interpret=interpret)
+        out, lse = fwd(q, k, v)
         return out, (q, k, v, out, lse)
 
     def _bwd(res, do):
         q, k, v, out, lse = res
         return flash_attention_bwd_pallas(
             q, k, v, out, lse, do, causal=causal, window=window, scale=scale,
-            block_q=block_q, block_kv=block_kv, interpret=interpret)
+            block_q=block_q or 128, block_kv=block_kv or 128,
+            interpret=interpret)
 
     _fa.defvjp(_fwd, _bwd)
     return _fa(q, k, v)

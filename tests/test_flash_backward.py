@@ -53,3 +53,57 @@ def test_fwd_lse_matches_logsumexp():
     got = lse.reshape(B, H, 1, S)[:, :, 0]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("Sq,Skv,window,tile", [
+    (1024, 1024, 0, 512),
+    (1024, 1024, 300, 128),     # tiles behind the window skipped
+    (512, 1024, 300, 512),      # q_offset 512
+])
+def test_fwd_lse_at_large_tiles(Sq, Skv, window, tile):
+    """The LSE the backward reads, from the forward's large, skipping
+    tiles, equals log-sum-exp of the masked scaled scores."""
+    key = jax.random.PRNGKey(2)
+    H, hd = 2, 32
+    q = jax.random.normal(key, (1, Sq, H, hd))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, Skv, H, hd))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, Skv, H, hd))
+    _, lse = flash_attention_fwd_pallas(q, k, v, causal=True, window=window,
+                                        block_q=tile, block_kv=tile,
+                                        interpret=True)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
+    q_pos = (Skv - Sq) + jnp.arange(Sq)[:, None]
+    k_pos = jnp.arange(Skv)[None, :]
+    mask = (k_pos <= q_pos) & ((k_pos > q_pos - window) if window else True)
+    s = jnp.where(mask[None, None], s, -1e30)
+    want = jax.nn.logsumexp(s, axis=-1)          # [B,H,Sq]
+    got = lse.reshape(1, H, 1, Sq)[:, :, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("S,H,KV,window", [
+    (1024, 2, 1, 0),            # forward at 512 tiles, backward at 128
+    (1024, 2, 2, 300),
+])
+def test_flash_backward_after_planned_forward(S, H, KV, window):
+    """Default tiles: the forward takes the plan's (512), the backward its
+    128, and reads the forward's LSE."""
+    key = jax.random.PRNGKey(3)
+    q = jax.random.normal(key, (1, S, H, 32))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, S, KV, 32))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, S, KV, 32))
+
+    def loss_pal(q, k, v):
+        o = flash_attention_pallas(q, k, v, causal=True, window=window,
+                                   interpret=True)
+        return jnp.sum(o ** 2)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(ref.attention_ref(q, k, v, causal=True, window=window) ** 2)
+
+    gp = jax.grad(loss_pal, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gp, gr, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-4, err_msg=name)

@@ -8,7 +8,9 @@ import pytest
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.fedagg import fedagg_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention import (flash_attention_fwd_pallas,
+                                           flash_attention_pallas,
+                                           fwd_tile_plan)
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssm_scan import ssm_scan_pallas
 
@@ -54,6 +56,66 @@ def test_flash_attention_nondivisible_kv():
     want = ref.attention_ref(q, k, v, causal=False)
     got = ops.flash_attention(q, k, v, causal=False, block_kv=64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("Sq,Skv,H,KV,causal,window,tile", [
+    (1024, 1024, 2, 1, True, 0, 512),      # GQA G=2, 3 of 4 tiles run
+    (1024, 1024, 2, 1, True, 0, None),     # tiles from the plan (512)
+    (1024, 1024, 2, 2, True, 300, 128),    # the window empties whole tiles
+    (1024, 1024, 2, 2, True, 300, 512),
+    (512, 1024, 2, 1, True, 300, 256),     # q_offset 512
+    (256, 1024, 2, 2, True, 0, 256),
+    (1024, 1024, 2, 2, False, 0, 512),     # nothing skipped
+    (256, 1024, 2, 1, False, 200, None),   # keys behind the window skipped
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_forward_tiles(Sq, Skv, H, KV, causal, window, tile, dtype):
+    """The forward at 128/256/512 tiles, skipping the tiles the mask
+    empties, against the full-scores oracle."""
+    q = rand((1, Sq, H, 32), dtype, 1)
+    k = rand((1, Skv, KV, 32), dtype, 2)
+    v = rand((1, Skv, KV, 32), dtype, 3)
+    want = ref.attention_ref(q, k, v, causal=causal, window=window)
+    got, _ = flash_attention_fwd_pallas(q, k, v, causal=causal, window=window,
+                                        block_q=tile, block_kv=tile,
+                                        interpret=True)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **tol(dtype))
+
+
+def _tiles_with_a_key(Sq, Skv, bq, bk, causal, window):
+    """Tiles holding at least one unmasked (query, key) pair, from the
+    oracle's dense mask."""
+    q_pos = (Skv - Sq) + np.arange(Sq)[:, None]
+    k_pos = np.arange(Skv)[None, :]
+    mask = np.ones((Sq, Skv), bool)
+    if causal:
+        mask &= k_pos <= q_pos
+    if window:
+        mask &= k_pos > q_pos - window
+    return int(mask.reshape(Sq // bq, bq, Skv // bk, bk).any(axis=(1, 3)).sum())
+
+
+@pytest.mark.parametrize("Sq,Skv,kw,want", [
+    (1024, 1024, {}, (512, 512, 3, 4)),
+    (1024, 1024, dict(causal=False), (512, 512, 4, 4)),
+    (384, 384, {}, (128, 128, 6, 9)),
+    (768, 768, {}, (256, 256, 6, 9)),
+    (64, 64, {}, (64, 64, 1, 1)),
+    (1024, 1024, dict(window=2047), (512, 512, 3, 4)),
+    (1024, 1024, dict(window=300, block_q=128, block_kv=128), (128, 128, 26, 64)),
+    (512, 1024, dict(window=300, block_q=128, block_kv=128), (128, 128, 16, 32)),
+    (256, 1024, dict(causal=False, window=200), (256, 512, 1, 2)),
+    (1024, 1024, dict(block_q=64, block_kv=128), (64, 128, 72, 128)),
+])
+def test_fwd_tile_plan(Sq, Skv, kw, want):
+    """Tiles from the lengths (explicit ones honoured); the tiles that run
+    are exactly those the mask leaves a key in."""
+    plan = fwd_tile_plan(Sq, Skv, **kw)
+    assert plan == want
+    bq, bk, run, _ = plan
+    assert run == _tiles_with_a_key(Sq, Skv, bq, bk, kw.get("causal", True),
+                                    kw.get("window", 0))
 
 
 # ------------------------------------------------------------ decode attention

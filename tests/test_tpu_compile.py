@@ -2,7 +2,9 @@
 
 Each test compiles for a described (not attached) TPU v5e: ``fedagg`` at
 C = 4 clients x M = Qwen1.5-0.5B's parameter count, flash attention
-forward and backward at B=4, S=512, H=KV=16, hd=64. Mosaic refuses what
+forward and backward at B=4, S=512, H=KV=16, hd=64 and at Phi-3-mini's
+(2, 1024, 32, 96) with its 2047-token window, where the forward takes
+512 tiles and skips the ones above the diagonal. Mosaic refuses what
 interpret mode accepts (1-D blocks, in-kernel gathers, unaligned tiles,
 too much VMEM), and the compiler refuses a program that does not fit the
 chip's HBM, so these guard every change to the kernels without a chip.
@@ -23,6 +25,7 @@ from repro.utils import param_count
 
 C = 4
 ATTN = (4, 512, 16, 64)
+PHI3_ATTN = (2, 1024, 32, 96)
 HBM = 16 * 2**30
 
 
@@ -78,12 +81,19 @@ def test_fedagg_compiles_at_lm_width(one_chip, m_total, variant):
     assert mem.temp_size_in_bytes < 2**20
 
 
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_flash_attention_compiles(one_chip, direction):
-    qkv = [jax.ShapeDtypeStruct(ATTN, jnp.bfloat16, sharding=one_chip)] * 3
+@pytest.mark.parametrize("direction,shape,window", [
+    ("fwd", ATTN, 0), ("bwd", ATTN, 0),
+    ("fwd", PHI3_ATTN, 2047), ("bwd", PHI3_ATTN, 2047),
+], ids=["fwd", "bwd", "fwd-phi3", "bwd-phi3"])
+def test_flash_attention_compiles(one_chip, direction, shape, window):
+    qkv = [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)] * 3
+
+    def attn(q, k, v):
+        return flash_attention_pallas(q, k, v, window=window)
+
     if direction == "fwd":
-        _compile(flash_attention_pallas, *qkv)
+        _compile(attn, *qkv)
     else:
-        _compile(jax.grad(lambda q, k, v: flash_attention_pallas(q, k, v)
+        _compile(jax.grad(lambda q, k, v: attn(q, k, v)
                           .astype(jnp.float32).sum(), argnums=(0, 1, 2)),
                  *qkv)
