@@ -33,11 +33,12 @@ NUMBERS = ("loss_gap", "gates_diff", "delta1_gap", "change3_gap")
 QUIET = 1e-3
 
 
-def change_norms_fn(mc):
+def change_norms_fn(family, mc):
     """jit: (key, params) -> {leaf: norm of params - weights(key)}, with the
-    stacked layer axis split into one leaf per layer."""
+    stacked layer axis (the program's ``periods``) split into one leaf per
+    layer."""
     def fn(key, params):
-        init = init_flat(mc, key)
+        init = init_flat(family, mc, key)
         out = {}
         for path, x in flatten(params).items():
             d = (x.astype(jnp.float32) - init[path]).astype(jnp.float32)

@@ -16,13 +16,16 @@ def unchanged(sess, step):
 
 
 def half_batch(sess, step):
-    """Half of every client's rows left out: the loss is the mean over the
-    rest."""
+    """Half of every client's batch left out: the loss is the mean over the
+    rest. The batch is halved by rows ([C, rows, seq]); where a client has
+    one row, by the second half of that row's positions."""
     def broken(state, batch, r):
         mask = batch["clients"]["mask"]
-        half = mask.shape[1] // 2
-        batch = dict(batch, clients=dict(batch["clients"],
-                                         mask=mask.at[:, half:].set(0.0)))
+        if mask.shape[1] >= 2:
+            mask = mask.at[:, mask.shape[1] // 2:].set(0.0)
+        else:
+            mask = mask.at[..., mask.shape[-1] // 2:].set(0.0)
+        batch = dict(batch, clients=dict(batch["clients"], mask=mask))
         return step(state, batch, r)
     return broken
 

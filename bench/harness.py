@@ -6,7 +6,11 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own under ``bench/``, found by the name that
 ``BENCHMARK.json`` gives it:
 
-- ``bench/configs/<config>.json``: the model as it is run;
+- ``bench/configs/<config>.json``: the model as it is run, and the name
+  of its family;
+- ``bench/families/<family>.py``: the model family, which maps the
+  configuration's keys onto the program's ``ModelConfig`` and gives the
+  program's parameter layout, the plain model loss and the model's counts;
 - ``bench/traffic/<traffic>.json``: the federation and its rounds;
 - ``bench/metrics/<metric>.py``: a reader with ``read(ctx)``;
 - ``bench/limits/<cell>.json``: the limits of the correctness check.
@@ -39,16 +43,6 @@ from traffic.generator import RowDraws, federation
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 SET_UP_ROUNDS = 3
-# program ModelConfig field <- configuration file key
-CONFIG_KEYS = {"num_layers": "num_hidden_layers", "d_model": "hidden_size",
-               "num_heads": "num_attention_heads",
-               "num_kv_heads": "num_key_value_heads",
-               "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-               "head_dim": "head_dim", "qkv_bias": "attention_bias",
-               "tie_embeddings": "tie_word_embeddings",
-               "rope_theta": "rope_theta", "param_dtype": "param_dtype",
-               "compute_dtype": "compute_dtype"}
-PROGRAM_NORM_EPS = 1e-6
 
 
 def log(msg):
@@ -72,6 +66,7 @@ def resolve(root, workload):
     cell = cells[workload]
     conf = {c["name"]: c for c in spec["configs"]}[cell["config"]]
     bench = os.path.join(root, spec["paths"][0])
+    mc = _load_json(os.path.join(root, conf["file"]))
 
     def applies(metric):
         return (workload in metric["workloads"] if "workloads" in metric
@@ -83,8 +78,8 @@ def resolve(root, workload):
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
     return {
-        "name": workload, "cell": cell, "bench": bench,
-        "mc": _load_json(os.path.join(root, conf["file"])),
+        "name": workload, "cell": cell, "bench": bench, "mc": mc,
+        "family": family_module(bench, mc, conf["file"]),
         "traffic": _load_json(os.path.join(bench, "traffic",
                                            cell["traffic"] + ".json")),
         "limits": _load_json(os.path.join(bench, "limits",
@@ -100,6 +95,20 @@ def load_module(name, path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def family_module(bench, mc, file):
+    """``bench/families/<family>.py``, for the family the configuration
+    file names; exits where it names none, or one that is not there."""
+    here = sorted(f[:-3] for f in os.listdir(os.path.join(bench, "families"))
+                  if f.endswith(".py"))
+    name = mc.get("family")
+    if name not in here:
+        named = f"family {name!r}" if name else "no family"
+        raise SystemExit(f"{file} names {named}; the families in "
+                         f"bench/families/ are {here}")
+    return load_module(f"family_{name}",
+                       os.path.join(bench, "families", name + ".py"))
 
 
 def metric_reader(bench, name):
@@ -132,15 +141,13 @@ def chip_devices(res):
     return devices[:chips]
 
 
-def model_config(mc):
+def model_config(family, mc):
     """The program's ModelConfig for the configuration file, every key
-    the file states set from it."""
+    that the family maps set from it."""
     from repro.configs import get_config
-    if mc["rms_norm_eps"] != PROGRAM_NORM_EPS or mc["hidden_act"] != "silu":
-        raise SystemExit("the program's dense block has rms_norm_eps "
-                         f"{PROGRAM_NORM_EPS} and silu")
+    family.require(mc)
     return get_config(mc["arch"]).replace(
-        **{field: mc[key] for field, key in CONFIG_KEYS.items()})
+        **{field: mc[key] for field, key in family.KEYS.items()})
 
 
 def fed_config(traffic):
@@ -164,7 +171,8 @@ class Session:
         from repro.models import get_model
         self.res, self.devices = res, list(devices)
         self.mc, self.tr = res["mc"], res["traffic"]
-        self.cfg = model_config(self.mc)
+        self.family = res["family"]
+        self.cfg = model_config(self.family, self.mc)
         self.fed = fed_config(self.tr)
         self.model = get_model(self.cfg)
         self.mesh = make_host_mesh(devices=self.devices)
@@ -187,13 +195,13 @@ class Session:
         self.mode = "temporal" if self.fsdp else "spatial"
         self.state_sh, self.batch_sh = self._shardings(self.fsdp)
         self.init = self._make_init()
-        self.norms = check.change_norms_fn(self.mc)
+        self.norms = check.change_norms_fn(self.family, self.mc)
         self.state = None
 
     def _check_layout(self):
         """The reference's weights have the program's layout."""
         prog = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
-        ours = jax.eval_shape(reference.make_init(self.mc),
+        ours = jax.eval_shape(reference.make_init(self.family, self.mc),
                               jax.random.PRNGKey(0))
         if (jax.tree.structure(prog) != jax.tree.structure(ours)
                 or jax.tree.leaves(prog) != jax.tree.leaves(ours)):
@@ -251,7 +259,7 @@ class Session:
     def _make_init(self):
         from repro.fl import engine
         fn = lambda key: engine.init_state(                      # noqa: E731
-            reference.nest(reference.init_flat(self.mc, key)), self.fed,
+            reference.init_params(self.family, self.mc, key), self.fed,
             self.C)
         return jax.jit(fn, out_shardings=self.state_sh)
 
@@ -339,9 +347,10 @@ class Session:
         chip, from the same seed and rows. ``follow``: the gates of the
         observations it is compared with, taken where the reference's own
         loss gap lies within ``tol`` of eps (``Reference.round``)."""
-        ref = reference.Reference(self.mc, self.tr, precision)
+        ref = reference.Reference(self.family, self.mc, self.tr, precision)
         with jax.default_device(self.devices[0]):
-            params = ref.cast(reference.make_init(self.mc)(self.key))
+            params = ref.cast(reference.make_init(self.family,
+                                                  self.mc)(self.key))
             obs = {"server_loss": [], "local_losses": [], "gates": [],
                    "margin": []}
             for r in range(SET_UP_ROUNDS):
@@ -458,13 +467,13 @@ def run(res, seed, seconds, trace, *, t_start, devices, trace_dir=None,
         tracemod = load_module("bench_trace", os.path.join(BENCH, "trace.py"))
         tr_data = tracemod.reduce(xplane, n_devices=len(devices))
         shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = {"mc": res["mc"], "traffic": tr, "rounds": rounds,
-               "trace": tr_data,
+        ctx = {"mc": res["mc"], "family": res["family"], "traffic": tr,
+               "rounds": rounds, "trace": tr_data,
                "mode": sess.mode, "chips": len(devices),
                "program_bytes": sess.program_bytes(),
                "peaks": peaks_for(res["bench"], d0.device_kind)
                if d0.platform == "tpu" else None,
-               "flops": flops, "m_total": int(flops.n_params(res["mc"])),
+               "flops": flops,
                "kernel_seconds": tracemod.kernel_seconds}
         for m in res["per_layer"]:
             value = res["readers"][m["name"]](ctx)

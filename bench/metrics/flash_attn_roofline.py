@@ -9,15 +9,15 @@ a lower share."""
 import re
 import sys
 
-# Pallas calls carry no kernel name in the trace: every one is a custom call
-# to "tpu_custom_call". The round's only other Pallas kernel, fedagg, is the
-# one whose output is a single [1, M] row.
-PALLAS = 'custom_call_target="tpu_custom_call"'
-FEDAGG = re.compile(r"^%?[\w.\-]+ = \w+\[1,\d+\]\{[^}]*\} custom-call\(")
+# the program names its flash kernels kernel.flash_fwd, kernel.flash_bwd_dq
+# and kernel.flash_bwd_dkv; the trace names an operation by its instruction
+# ("%kernel.flash_fwd.37 = ..."), so another Pallas kernel, fedagg or a
+# family's own, is not counted
+FLASH = re.compile(r"^%?kernel\.flash_[\w.\-]* = ")
 
 
 def is_flash(name):
-    return PALLAS in name and not FEDAGG.match(name)
+    return bool(FLASH.match(name))
 
 
 def read(ctx):
@@ -31,8 +31,8 @@ def read(ctx):
     fl = by = 0.0
     for r in ctx["rounds"]:
         trained = int(sum(g > 0 for g in r["gates"]))
-        w = flops.round_work(ctx["mc"], ctx["traffic"], trained,
-                             train_calls=C if ctx["mode"] == "spatial"
+        w = flops.round_work(ctx["family"], ctx["mc"], ctx["traffic"],
+                             trained, train_calls=C if ctx["mode"] == "spatial"
                              else trained)
         fl += w["attn_flops"]
         by += w["attn_bytes"]

@@ -8,7 +8,7 @@ def read(ctx):
     peaks, tr, flops = ctx["peaks"], ctx["trace"], ctx["flops"]
     if peaks is None or tr["window_s"] <= 0 or not tr["busy_s"]:
         return None
-    need = sum(flops.round_work(ctx["mc"], ctx["traffic"],
+    need = sum(flops.round_work(ctx["family"], ctx["mc"], ctx["traffic"],
                                 int(sum(g > 0 for g in r["gates"])))
                ["model_flops"] for r in ctx["rounds"])
     return 100.0 * need / (tr["window_s"] * ctx["chips"]

@@ -1,17 +1,15 @@
-"""The plain reference: weights from the seed, and FedALIGN rounds of a
-dense decoder in straightforward ``jax.numpy``.
+"""The plain reference: weights from the seed, and FedALIGN rounds in
+straightforward ``jax.numpy``.
 
-Nothing here imports the program. The reference reads the model's sizes
-from the configuration file (``bench/configs/<config>.json``) and the
-federation's knobs from the traffic mix, and follows the program's
-parameter layout only so that both can start from the same weights:
-
-    embed [V, d], final_norm.scale [d], lm_head [d, V] (untied),
-    periods.l0.{norm1,norm2}.scale [L, d],
-    periods.l0.attn.{wq,wk,wv} [L, d, heads*hd], wo [L, heads*hd, d],
-    periods.l0.attn.{bq,bk,bv} (with attention_bias),
-    periods.l0.mlp.{w_gate,w_up} [L, d, ff], w_down [L, ff, d],
-    pre_blocks [] (no leading dense layers).
+Nothing here imports the program. The model is the configuration's
+family, ``bench/families/<family>.py``, which gives the program's
+parameter layout (``param_shapes``), the plain model loss (``loss``) and
+the model's counts; this module holds what every family shares: weights
+drawn leaf by leaf from the seed, the arithmetic of each precision, and
+the round. It reads the model's sizes from the configuration file
+(``bench/configs/<config>.json``) and the federation's knobs from the
+traffic mix, and follows the program's layout only so that both can
+start from the same weights.
 
 One round, as the paper states it: the server's loss F(w) on the server
 batch; each client's loss F_k(w) on its own batch; gates 1 for priority
@@ -39,34 +37,6 @@ PRECISIONS = ("f32", "bf16", "fp8")
 
 
 # ------------------------------------------------------------------ weights
-def param_shapes(mc):
-    """{path: (shape, kind)} of the program's parameter layout."""
-    d, V, L = mc["hidden_size"], mc["vocab_size"], mc["num_hidden_layers"]
-    H, KV, hd = (mc["num_attention_heads"], mc["num_key_value_heads"],
-                 mc["head_dim"])
-    ff = mc["intermediate_size"]
-    out = {"embed": ((V, d), "embed"), "final_norm.scale": ((d,), "norm")}
-    if not mc["tie_word_embeddings"]:
-        out["lm_head"] = ((d, V), "matrix")
-    p = "periods.l0."
-    out.update({
-        p + "norm1.scale": ((L, d), "norm"),
-        p + "norm2.scale": ((L, d), "norm"),
-        p + "attn.wq": ((L, d, H * hd), "matrix"),
-        p + "attn.wk": ((L, d, KV * hd), "matrix"),
-        p + "attn.wv": ((L, d, KV * hd), "matrix"),
-        p + "attn.wo": ((L, H * hd, d), "matrix"),
-        p + "mlp.w_gate": ((L, d, ff), "matrix"),
-        p + "mlp.w_up": ((L, d, ff), "matrix"),
-        p + "mlp.w_down": ((L, ff, d), "matrix"),
-    })
-    if mc["attention_bias"]:
-        out.update({p + "attn.bq": ((L, H * hd), "bias"),
-                    p + "attn.bk": ((L, KV * hd), "bias"),
-                    p + "attn.bv": ((L, KV * hd), "bias")})
-    return out
-
-
 def _leaf(key, path, shape, kind):
     k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7fffffff)
     if kind == "norm":
@@ -78,15 +48,19 @@ def _leaf(key, path, shape, kind):
             * fan_in ** -0.5)
 
 
-def nest(flat):
-    """{"a.b.c": x} -> {"a": {"b": {"c": x}}}, plus the empty pre_blocks."""
-    out = {"pre_blocks": []}
+def nest(flat, lists=()):
+    """{"a.b.c": x} -> {"a": {"b": {"c": x}}}. The top-level containers
+    named in ``lists`` are lists, ordered by index ("a.0.c", "a.1.c"),
+    and are there even where no path leads into them."""
+    out = {name: {} for name in lists}
     for path, x in flat.items():
         node = out
         *heads, last = path.split(".")
         for h in heads:
             node = node.setdefault(h, {})
         node[last] = x
+    for name in lists:
+        out[name] = [out[name][i] for i in sorted(out[name], key=int)]
     return out
 
 
@@ -105,20 +79,29 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0x7fffffff)
 
 
-def init_flat(mc, key):
-    """The weights as {path: f32 array}; traced inside one jit."""
+def init_flat(family, mc, key):
+    """The weights as {path: f32 array} in the family's layout; traced
+    inside one jit."""
     return {path: _leaf(key, path, shape, kind)
-            for path, (shape, kind) in param_shapes(mc).items()}
+            for path, (shape, kind) in family.param_shapes(mc).items()}
 
 
-def make_init(mc, shardings=None):
+def init_params(family, mc, key):
+    """The weights nested as the program nests them."""
+    return nest(init_flat(family, mc, key), family.LISTS)
+
+
+def make_init(family, mc, shardings=None):
     """One jitted call from the key to the nested weights on the device."""
-    fn = lambda key: nest(init_flat(mc, key))                    # noqa: E731
+    fn = lambda key: init_params(family, mc, key)                # noqa: E731
     return jax.jit(fn, out_shardings=shardings)
 
 
-# ---------------------------------------------------------------- the model
-class _Arith:
+# --------------------------------------------------------------- arithmetic
+class Arith:
+    """Matrix products in one precision, and the type that weights,
+    activations and updates take in it."""
+
     def __init__(self, precision):
         if precision not in PRECISIONS:
             raise ValueError(f"precision {precision!r} not in {PRECISIONS}")
@@ -147,12 +130,13 @@ def _fp8(x):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _rmsnorm(x, scale, eps):
+# ------------------------------------------------------------ shared layers
+def rmsnorm(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale
 
 
-def _rope(x, theta):
+def rope(x, theta):
     S, hd = x.shape[1], x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     ang = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs
@@ -160,54 +144,6 @@ def _rope(x, theta):
     sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _block(mc, ar, x, p):
-    B, S, d = x.shape
-    H, KV, hd = (mc["num_attention_heads"], mc["num_key_value_heads"],
-                 mc["head_dim"])
-    eps = mc["rms_norm_eps"]
-    h = _rmsnorm(x, p["norm1"]["scale"], eps)
-    a = p["attn"]
-    q, k, v = ar.mm(h, a["wq"]), ar.mm(h, a["wk"]), ar.mm(h, a["wv"])
-    if "bq" in a:
-        q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-    q = _rope(q.reshape(B, S, H, hd), mc["rope_theta"])
-    k = _rope(k.reshape(B, S, KV, hd), mc["rope_theta"])
-    v = v.reshape(B, S, KV, hd)
-    if KV != H:
-        k, v = jnp.repeat(k, H // KV, axis=2), jnp.repeat(v, H // KV, axis=2)
-    s = ar.einsum("bqhd,bkhd->bhqk", q, k) * jnp.asarray(hd ** -0.5, x.dtype)
-    qi, ki = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
-    allowed = ki <= qi
-    window = (mc.get("sliding_window")
-              if mc.get("use_sliding_window", True) else None)
-    if window and window < S:
-        allowed &= qi - ki < window
-    s = jnp.where(allowed, s, jnp.asarray(-1e30, s.dtype))
-    w = jax.nn.softmax(s, axis=-1)
-    o = ar.einsum("bhqk,bkhd->bqhd", w, v).reshape(B, S, H * hd)
-    x = x + ar.mm(o, a["wo"])
-    h = _rmsnorm(x, p["norm2"]["scale"], eps)
-    m = p["mlp"]
-    g = ar.mm(h, m["w_gate"])
-    return x + ar.mm(jax.nn.silu(g) * ar.mm(h, m["w_up"]), m["w_down"])
-
-
-def loss(mc, precision, params, batch):
-    """Mean next-token cross-entropy over the batch's masked positions."""
-    ar = _Arith(precision)
-    x = params["embed"][batch["tokens"]]
-    body = jax.checkpoint(lambda x, p: (_block(mc, ar, x, p), None))
-    x, _ = jax.lax.scan(body, x, params["periods"]["l0"])
-    x = _rmsnorm(x, params["final_norm"]["scale"], mc["rms_norm_eps"])
-    w = (params["embed"].T if mc["tie_word_embeddings"]
-         else params["lm_head"])
-    logits = ar.mm(x, w).astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, batch["labels"][..., None], -1)[..., 0]
-    mask = batch["mask"].astype(jnp.float32)
-    return jnp.sum((logz - gold) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
 # ---------------------------------------------------------------- one round
@@ -228,14 +164,14 @@ def round_batches(fed_data, draws):
 
 
 class Reference:
-    """Jitted pieces of one plain round, for one configuration, one traffic
-    mix and one precision."""
+    """Jitted pieces of one plain round, for one configuration of a family,
+    one traffic mix and one precision."""
 
-    def __init__(self, mc, traffic, precision="f32"):
+    def __init__(self, family, mc, traffic, precision="f32"):
         self.mc, self.traffic, self.precision = mc, traffic, precision
-        ar = _Arith(precision)
+        ar = Arith(precision)
         self.dtype = ar.dtype
-        lossf = functools.partial(loss, mc, precision)
+        lossf = functools.partial(family.loss, mc, precision)
         lr = jnp.asarray(traffic["lr"], ar.dtype)
         E = traffic["local_steps"]
 

@@ -3,17 +3,19 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; its
-configuration, traffic mix, per-layer metric readers and limits are files
-under ``bench/`` found by name (``bench/harness.py``). The run exits
-nonzero and prints no result where JAX finds no TPU, fewer chips than the
-cell asks for, or a device kind that the peaks table (``bench/peaks.json``)
-does not hold. Otherwise it sets up (weights from the seed, the round
-compiled, the first rounds run and kept for the correctness check), runs
-rounds for ``--seconds``, checks the first rounds against the plain
-reference, and prints the numbers compared beside their limits as its last
-lines on standard error, and the result as the last line of standard
-output. ``--trace 1`` traces a window of the cell's ``trace_rounds``
-rounds and reports the per-layer metrics instead of the end-to-end ones.
+configuration, the model family that the configuration names
+(``bench/families/<family>.py``), traffic mix, per-layer metric readers
+and limits are files under ``bench/`` found by name
+(``bench/harness.py``). The run exits nonzero and prints no result where
+JAX finds no TPU, fewer chips than the cell asks for, or a device kind
+that the peaks table (``bench/peaks.json``) does not hold. Otherwise it
+sets up (weights from the seed, the round compiled, the first rounds run
+and kept for the correctness check), runs rounds for ``--seconds``, checks
+the first rounds against the plain reference, and prints the numbers
+compared beside their limits as its last lines on standard error, and the
+result as the last line of standard output. ``--trace 1`` traces a window
+of the cell's ``trace_rounds`` rounds and reports the per-layer metrics
+instead of the end-to-end ones.
 """
 import time
 

@@ -12,7 +12,8 @@ for p in (BENCH, os.path.join(ROOT, "src")):
         sys.path.insert(0, p)
 
 TINY_CONFIG = {
-    "arch": "qwen1.5-0.5b", "source": "https://huggingface.co/Qwen/Qwen1.5-0.5B",
+    "arch": "qwen1.5-0.5b", "family": "dense",
+    "source": "https://huggingface.co/Qwen/Qwen1.5-0.5B",
     "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 4, "head_dim": 16, "num_hidden_layers": 2,
     "vocab_size": 256, "tie_word_embeddings": True, "attention_bias": True,

@@ -40,6 +40,16 @@ def test_a_broken_step_is_not_correct(tiny_root, fault):
     assert not out["correct"], out["checks"]
 
 
+def test_half_batch_of_one_row_is_not_correct(tmp_path):
+    # one row a client, as the cross-device cell has: the fault leaves out
+    # the second half of the row's positions
+    from conftest import TINY_TRAFFIC
+    root = make_root(tmp_path, traffic=dict(TINY_TRAFFIC, per_client=1))
+    assert _run(root)["correct"]
+    out = _run(root, half_batch)
+    assert not out["correct"], out["checks"]
+
+
 CHILD = r"""
 import json, sys, time
 sys.path[:0] = [{bench!r}, {src!r}, {tests!r}]

@@ -16,7 +16,7 @@ SPANS = [("bench.build", 0, 100), ("bench.put", 100, 150),
          ("bench.step", 150, 160), ("bench.wait", 160, 900),
          ("bench.read", 900, 1000)]
 PALLAS = 'custom_call_target="tpu_custom_call"'
-FLASH = ("%checkpoint.3 = (bf16[2,1,8,4]{3,2,1,0}, f32[2,1,8]{2,1,0}) "
+FLASH = ("%kernel.flash_fwd.3 = (bf16[2,1,8,4]{3,2,1,0}, f32[2,1,8]{2,1,0}) "
          "custom-call(bf16[2,1,8,4]{3,2,1,0} %q), " + PALLAS)
 FEDAGG = ("%custom-call.9 = f32[1,4096]{1,0} custom-call(f32[2,4096]{1,0} %u,"
           " f32[2,1]{1,0} %w, f32[2,1]{1,0} %g), " + PALLAS)
@@ -60,7 +60,7 @@ def test_kernel_seconds_by_name(red):
         (150 + 200 + 100) / 2 * NS)
     assert trace.kernel_seconds(red, r"no_such_kernel") is None
     labels = dict(red["top_ops"])
-    assert labels["checkpoint.3 custom-call tpu_custom_call"] == \
+    assert labels["kernel.flash_fwd.3 custom-call tpu_custom_call"] == \
         pytest.approx(105 * NS)
     assert "while.5 while" not in labels
 
