@@ -20,6 +20,7 @@ ARCH_IDS = [
     "deepseek_moe_16b",
     "granite_moe_3b_a800m",
     "qwen1_5_0_5b",
+    "deepseek_v2_lite",
 ]
 
 # dashed aliases matching the assignment table
@@ -34,6 +35,7 @@ ALIASES = {
     "deepseek-moe-16b": "deepseek_moe_16b",
     "granite-moe-3b-a800m": "granite_moe_3b_a800m",
     "qwen1.5-0.5b": "qwen1_5_0_5b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

@@ -33,11 +33,19 @@ class ModelConfig:
 
     # --- MLA (DeepSeek/MiniCPM3-style latent attention) ---------------------
     mla: bool = False
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0              # 0 = a plain query projection wq
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+
+    # --- YaRN rope scaling (DeepSeek-V2's static blend) -----------------------
+    yarn_factor: float = 0.0          # 0 = plain rope
+    yarn_original_max_position: int = 0
+    yarn_mscale_all_dim: float = 0.0  # the temperature term; 0 = none, the
+                                      # softmax scale left as hd**-0.5. cos
+                                      # and sin stay unscaled, as where the
+                                      # published mscale equals it
 
     # --- MoE ----------------------------------------------------------------
     moe: bool = False
@@ -45,9 +53,14 @@ class ModelConfig:
     num_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0                 # per-expert hidden dim
+    router_experts: int = 0           # router width; 0 -> num_experts. A
+                                      # layer that holds num_experts of
+                                      # them (experts 0..num_experts-1)
+                                      # routes over all router_experts and
+                                      # computes only its own experts' part
+    norm_topk_prob: bool = True       # renormalise the top-k weights to 1
     moe_every: int = 1                # MoE FFN on layers where (i % moe_every == moe_offset)
     moe_offset: int = 0
-    capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
 
     # --- layer pattern ------------------------------------------------------
@@ -104,8 +117,14 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.ssm_dt_rank == 0:
             object.__setattr__(self, "ssm_dt_rank", -(-self.d_model // 16))
+        validate_model(self)
 
     # ------------------------------------------------------------------ helpers
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores (``router_experts``, else all held)."""
+        return self.router_experts or self.num_experts
+
     @property
     def pdtype(self):
         return jnp.dtype(self.param_dtype)
@@ -146,6 +165,26 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+
+def validate_model(cfg: ModelConfig) -> None:
+    """The ModelConfig fields that must agree with each other; raises
+    ``ValueError``. Runs on every construction and ``replace``."""
+    if cfg.moe:
+        if not 0 < cfg.top_k <= cfg.router_width:
+            raise ValueError(f"{cfg.name}: top_k={cfg.top_k} must lie in "
+                             f"1..{cfg.router_width} (the router's width)")
+        if not 0 < cfg.num_experts <= cfg.router_width:
+            raise ValueError(
+                f"{cfg.name}: the layer holds num_experts={cfg.num_experts} "
+                f"of router_experts={cfg.router_experts}; it must hold 1 to "
+                "all of them")
+    if cfg.yarn_factor:
+        if cfg.yarn_factor <= 1 or cfg.yarn_original_max_position <= 0:
+            raise ValueError(
+                f"{cfg.name}: YaRN needs yarn_factor > 1 and "
+                "yarn_original_max_position > 0, got "
+                f"{cfg.yarn_factor} and {cfg.yarn_original_max_position}")
 
 
 @dataclass(frozen=True)

@@ -86,6 +86,7 @@ from repro.core.aggregation import (aggregator_key, apply_server_opt,
 from repro.core.alignment import epsilon_at
 from repro.fl import engine
 from repro.kernels import ops as kops
+from repro.models import moe
 from repro.sharding.specs import dp_axes, tp_axes
 from repro.utils import fold_in_name, tree_axpy, tree_sub
 
@@ -176,25 +177,35 @@ def _phase(name):
     return jax.named_scope(f"fedalign.{name}")
 
 
+def _loss(model, params, batch):
+    """(loss, the expert layer's counters) of one forward; the counters are
+    {} for a model without expert layers, so that its round carries
+    nothing more."""
+    loss, metrics = model.loss_fn(params, batch)
+    return loss, {k: metrics[k] for k in moe.COUNTERS if k in metrics}
+
+
 def _train_steps(model, params, batch, lr, n_steps):
     """E local SGD steps on one client's batch (deterministic: full-batch
-    gradients, no PRNG — re-running them reproduces the update exactly)."""
+    gradients, no PRNG — re-running them reproduces the update exactly).
+    Returns (params', the counters of the E forwards)."""
     def step(p, _):
-        loss, grads = jax.value_and_grad(
-            lambda q: model.loss_fn(q, batch)[0])(p)
-        return tree_axpy(-lr, grads, p), loss
+        (_, counts), grads = jax.value_and_grad(
+            lambda q: _loss(model, q, batch), has_aux=True)(p)
+        return tree_axpy(-lr, grads, p), counts
 
-    params, _ = jax.lax.scan(step, params, None, length=n_steps)
-    return params
+    params, counts = jax.lax.scan(step, params, None, length=n_steps)
+    return params, moe.fold_counts(counts)
 
 
 def _local_steps(model, params, batch, lr, n_steps):
     """Local training plus F_k(w_t) of the *received* model (the paper's
-    matching statistic). Returns (params', loss0)."""
+    matching statistic). Returns (params', loss0, counters)."""
     with _phase("eval"):
-        loss0, _ = model.loss_fn(params, batch)
+        loss0, counts = _loss(model, params, batch)
     with _phase("train"):
-        return _train_steps(model, params, batch, lr, n_steps), loss0
+        params, train_counts = _train_steps(model, params, batch, lr, n_steps)
+        return params, loss0, moe.add_counts(counts, train_counts)
 
 
 def _gate_ctx(fed, state, util_ema, local_losses, server_loss, pm, w,
@@ -295,10 +306,13 @@ def _failure_stats(fed, stats, lost, nonfinite_skips):
 
 
 def _server_step(fed, state, agg_delta, mass, server_loss, local_losses,
-                 sel_gates, gates, util_ema, ef_accum, lost, pm, w):
+                 sel_gates, gates, util_ema, ef_accum, lost, pm, w, counts):
     """The round's last phase, shared by both pod rounds: the divergence
     guard, the server optimizer (or the in-flight buffer), the next
-    FederationState and the round's stats. Returns (new_state, stats)."""
+    FederationState and the round's stats, with the expert layer's
+    ``counts`` over the round's forwards (the server loss, the eval
+    pre-pass and the local steps whose update is aggregated) where the
+    model has one. Returns (new_state, stats)."""
     clock_on = fed.latency_mode != "none"
     finite = engine.aggregate_finite(fed, agg_delta, server_loss)
     push_timer = (engine.slot_timer(fed, state.latency, gates)
@@ -319,6 +333,7 @@ def _server_step(fed, state, agg_delta, mass, server_loss, local_losses,
         "theta_round": 1.0 / (1.0 + jnp.sum((1 - pm.astype(jnp.float32)) * w * gates)),
     }, applied, inflight)
     stats = _failure_stats(fed, stats, lost, new_state.nonfinite_skips)
+    stats.update(counts)
     return new_state, stats
 
 
@@ -434,7 +449,7 @@ def make_spatial_round(model, fed, num_clients: int):
         C = pm.shape[0]
 
         with _phase("server_loss"):
-            server_loss, _ = model.loss_fn(params, batch["server"])
+            server_loss, counts = _loss(model, params, batch["server"])
         ef_accum = state.ef_accum
 
         # fault injection mirrors the engine round: availability folds into
@@ -455,8 +470,8 @@ def make_spatial_round(model, fed, num_clients: int):
         if use_cohort:
             # eval -> gates -> gather-train: only K cohort slots pay E steps
             with _phase("eval"):
-                local_losses = _client_vmap(
-                    lambda cb: model.loss_fn(params, cb)[0])(client_batch)
+                local_losses, eval_counts = _client_vmap(
+                    lambda cb: _loss(model, params, cb))(client_batch)
             with _phase("gate"):
                 util_ema = engine.utility_update(fed, state.util_ema,
                                                  local_losses, server_loss)
@@ -470,9 +485,12 @@ def make_spatial_round(model, fed, num_clients: int):
                     backlog_boost=float(fed.backlog_boost))
                 cohort_batch = jax.tree.map(lambda a: a[idx], client_batch)
             with _phase("train"):
-                cohort_params = _client_vmap(
+                cohort_params, train_counts = _client_vmap(
                     lambda cb: _train_steps(model, params, cb, lr, E))(
                     cohort_batch)
+                counts = moe.add_counts(counts, moe.add_counts(
+                    moe.fold_counts(eval_counts),
+                    moe.fold_counts(train_counts)))
             with _phase("gate"):
                 if ctf is not None:
                     cohort_params = ctf(cohort_params, params, idx)
@@ -500,8 +518,10 @@ def make_spatial_round(model, fed, num_clients: int):
                     agg_delta = engine.server_delta(fed, params, cohort_params,
                                                     agg_w, agg_g, key=akey)
         else:
-            client_params, local_losses = _client_vmap(
+            client_params, local_losses, client_counts = _client_vmap(
                 lambda cb: _local_steps(model, params, cb, lr, E))(client_batch)
+            with _phase("train"):
+                counts = moe.add_counts(counts, moe.fold_counts(client_counts))
             with _phase("gate"):
                 util_ema = engine.utility_update(fed, state.util_ema,
                                                  local_losses, server_loss)
@@ -545,7 +565,7 @@ def make_spatial_round(model, fed, num_clients: int):
             return _server_step(fed, state, agg_delta,
                                 inclusion_mass(fed, agg_w, agg_g),
                                 server_loss, local_losses, sel_gates, gates,
-                                util_ema, ef_accum, lost, pm, w)
+                                util_ema, ef_accum, lost, pm, w, counts)
 
     return _kernels_per_shard(_pool_wrap(fed, round_step))
 
@@ -615,8 +635,9 @@ def make_temporal_round(model, fed, cohort: int):
         w = batch["weights"]
         C = pm.shape[0]
         with _phase("server_loss"):
-            server_loss, _ = model.loss_fn(params, batch["server"])
+            server_loss, counts = _loss(model, params, batch["server"])
         ef_accum = state.ef_accum
+        no_counts = jax.tree.map(jnp.zeros_like, counts)
 
         # fault injection (corruption excluded above): availability masks
         # selection, crashes/deadline-late clients lose their mass
@@ -632,8 +653,9 @@ def make_temporal_round(model, fed, cohort: int):
         # eval pre-pass: F_k(w_t) for the whole cohort before any gate is
         # fixed (rank-based strategies need the full loss vector)
         with _phase("eval"):
-            local_losses = jax.lax.map(
-                lambda cb: model.loss_fn(params, cb)[0], batch["clients"])
+            local_losses, eval_counts = jax.lax.map(
+                lambda cb: _loss(model, params, cb), batch["clients"])
+            counts = moe.add_counts(counts, moe.fold_counts(eval_counts))
         with _phase("gate"):
             util_ema = engine.utility_update(fed, state.util_ema,
                                              local_losses, server_loss)
@@ -648,7 +670,7 @@ def make_temporal_round(model, fed, cohort: int):
 
             def sketch_client(carry, cbatch):
                 with _phase("train"):
-                    p_k = _train_steps(model, params, cbatch, lr, E)
+                    p_k, _ = _train_steps(model, params, cbatch, lr, E)
                 with _phase("gate"):
                     return carry, engine.delta_sketch(tree_sub(p_k, params),
                                                       skey, dim)
@@ -677,7 +699,7 @@ def make_temporal_round(model, fed, cohort: int):
                 return jax.lax.cond(
                     gate > 0,
                     lambda b: _train_steps(model, params, b, lr, E),
-                    lambda b: params, cbatch)
+                    lambda b: (params, no_counts), cbatch)
 
         if robust_gather:
             # robust/private aggregators need every client's delta at once
@@ -687,17 +709,18 @@ def make_temporal_round(model, fed, cohort: int):
             # seam.
             def per_client_stack(stacked, inp):
                 k, cbatch, gate = inp
-                p_k = train_if_gated(cbatch, gate)
+                p_k, c_k = train_if_gated(cbatch, gate)
                 with _phase("aggregate"):
                     return jax.tree.map(
                         lambda s, p: jax.lax.dynamic_update_index_in_dim(
-                            s, p, k, 0), stacked, p_k), None
+                            s, p, k, 0), stacked, p_k), c_k
 
             with _phase("aggregate"):
                 empty = jax.tree.map(
                     lambda p: jnp.zeros((C,) + p.shape, p.dtype), params)
-            stacked, _ = jax.lax.scan(per_client_stack, empty,
-                                      (jnp.arange(C), batch["clients"], gates))
+            stacked, train_counts = jax.lax.scan(
+                per_client_stack, empty,
+                (jnp.arange(C), batch["clients"], gates))
             with _phase("aggregate"):
                 akey = aggregator_key(fed, round_idx) if agg_needs_key else None
                 if ef_on:
@@ -712,18 +735,18 @@ def make_temporal_round(model, fed, cohort: int):
             def per_client(carry, inp):
                 acc_num, acc_den = carry
                 cbatch, w_k, gate = inp
-                p_k = train_if_gated(cbatch, gate)
+                p_k, c_k = train_if_gated(cbatch, gate)
                 with _phase("aggregate"):
                     wg = w_k * gate
                     acc_num = jax.tree.map(
                         lambda a, pk: a + wg * pk.astype(jnp.float32),
                         acc_num, p_k)
-                    return (acc_num, acc_den + wg), None
+                    return (acc_num, acc_den + wg), c_k
 
             with _phase("aggregate"):
                 zeros = jax.tree.map(
                     lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (num, den), _ = jax.lax.scan(
+            (num, den), train_counts = jax.lax.scan(
                 per_client, (zeros, jnp.float32(0)),
                 (batch["clients"], w, gates))
             # streamed aggregation accumulates f32 in the carry; the
@@ -740,9 +763,10 @@ def make_temporal_round(model, fed, cohort: int):
                         0.0),
                     num, params)
         with _phase("server_step"):
+            counts = moe.add_counts(counts, moe.fold_counts(train_counts))
             return _server_step(fed, state, agg_delta, mass, server_loss,
                                 local_losses, sel_gates, gates, util_ema,
-                                ef_accum, lost, pm, w)
+                                ef_accum, lost, pm, w, counts)
 
     return _kernels_per_shard(_pool_wrap(fed, round_step))
 

@@ -4,9 +4,12 @@ Every op has (a) a Pallas TPU kernel (``<name>.py``), (b) a jnp lowering
 here (chunked / memory-safe), and (c) a naive oracle in ``ref.py`` used by
 tests.
 
-``flash_attention`` and ``fedagg`` take ``use_pallas=None`` by default,
-which follows the platform (``pallas_default``): the compiled Pallas
-kernels where the default backend is a TPU, the jnp lowerings elsewhere.
+``flash_attention``, ``fedagg`` and ``expert_ffn`` take
+``use_pallas=None`` by default, which follows the platform
+(``pallas_default``): the compiled Pallas kernels where the default
+backend is a TPU, the jnp lowerings elsewhere. ``expert_ffn``'s grouped
+matmul (``grouped_matmul``) is the Pallas kernel that ships with JAX
+(megablox ``gmm``).
 Nothing falls back at run time: on a TPU an unsupported Pallas variant
 raises. ``decode_attention`` and ``ssm_scan`` keep the jnp lowering unless
 a caller passes ``use_pallas=True``, because their Pallas kernels do not
@@ -158,6 +161,134 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_kv=1024,
     return _flash_attention_jnp(q, k, v, causal=causal, window=window,
                                 block_kv=block_kv, kv_len=kv_len, scale=scale,
                                 mm_dtype=mm_dtype)
+
+
+# ============================================================ grouped matmul
+GMM_TM = 256        # rows a tile; the rows are padded to a multiple of it
+
+
+def _gmm_tile(x):
+    """A tile of the contraction or output dim: the whole dim up to 1536,
+    else the largest of 512, 256 and 128 that divides it."""
+    if x <= 1536:
+        return x
+    return next((t for t in (512, 256, 128) if x % t == 0), x)
+
+
+def _gmm_tiling(m, k, n):
+    """(m, k, n) tiles of one product; the backward's products look up
+    their own shapes here too."""
+    return GMM_TM, _gmm_tile(k), _gmm_tile(n)
+
+
+def _grouped_matmul_jnp(lhs, rhs, group_sizes):
+    """Every group's product on every row, each row keeping its own group's
+    (g times the work; ``jax.lax.ragged_dot`` cannot be vmapped over the
+    spatial round's clients)."""
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(lhs.shape[0])
+    member = (row >= (ends - group_sizes)[:, None]) & (row < ends[:, None])
+    every = jnp.einsum("mk,gkn->gmn", lhs, rhs)
+    return jnp.sum(jnp.where(member[..., None], every, 0), axis=0)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, use_pallas=None,
+                   interpret=False):
+    """[m, k] rows in contiguous groups, each times its group's [k, n] of
+    ``rhs`` [g, k, n] -> [m, n]: group i is the ``group_sizes[i]`` rows
+    after group i-1's, and the rows past the last group come out 0.
+
+    On TPU (``use_pallas=None``) the Pallas grouped matmul that ships with
+    JAX (megablox ``gmm``), which visits only the tiles that the groups
+    cover; elsewhere every group on every row, masked. The product is one
+    shard's: on a multi-device mesh ``expert_ffn`` places it."""
+    if use_pallas is None:
+        use_pallas = pallas_default()
+    if not use_pallas:
+        return _grouped_matmul_jnp(lhs, rhs, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m = lhs.shape[0]
+    pad = -m % GMM_TM
+    group_sizes = group_sizes.astype(jnp.int32)
+    # the rows past the groups form one more group, which has no rhs and
+    # which the kernel zeroes
+    rest = (m + pad - jnp.sum(group_sizes))[None]
+
+    return gmm(jnp.pad(lhs, ((0, pad), (0, 0))), rhs,
+               jnp.concatenate([group_sizes, rest]),
+               preferred_element_type=lhs.dtype, tiling=_gmm_tiling,
+               interpret=interpret)[:m]
+
+
+def _expert_ffn_local(xf, tope, topw, w_gate, w_up, w_down, first, mm):
+    """``expert_ffn`` on one shard, whose experts are ``first`` onwards:
+    f32 [T, d]."""
+    T, k = tope.shape
+    E = w_gate.shape[0]
+    with jax.named_scope("kernel.moe_route"):
+        e = tope.reshape(-1) - first                                  # [T*k]
+        # the held experts' slots first, one contiguous group each
+        e = jnp.where((e >= 0) & (e < E), e, E)
+        sizes = jnp.sum(e[:, None] == jnp.arange(E), axis=0, dtype=jnp.int32)
+        order = jnp.argsort(e, stable=True)
+        xs = jnp.repeat(xf, k, axis=0).at[order].get(unique_indices=True)
+    with jax.named_scope("kernel.moe_gmm"):
+        h = jax.nn.silu(mm(xs, w_gate, sizes)) * mm(xs, w_up, sizes)
+        ys = mm(h, w_down, sizes)                                 # 0 unheld
+    with jax.named_scope("kernel.moe_combine"):
+        slot = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=order.dtype), unique_indices=True)
+        return jnp.einsum("tkd,tk->td",
+                          ys.at[slot].get(unique_indices=True).reshape(
+                              T, k, -1), topw,
+                          preferred_element_type=jnp.float32)
+
+
+def expert_ffn(xf, tope, topw, w_gate, w_up, w_down, *, expert_parallel=False,
+               use_pallas=None, interpret=False):
+    """The held experts' SwiGLU of every routed slot, dropless: token rows
+    ``xf`` [T, d], routed to the experts ``tope`` [T, k] (numbered over the
+    router's whole width) with the weights ``topw`` [T, k]; ``w_gate``,
+    ``w_up`` [E, d, f] and ``w_down`` [E, f, d] hold experts 0..E-1.
+    Returns f32 [T, d]: each token's weighted sum over its slots to held
+    experts; a slot to any other expert adds nothing.
+
+    The slots are sorted by expert, so that each held expert's rows form
+    one group, and one ``grouped_matmul`` a projection computes them all,
+    whatever their load. Under ``kernels_per_shard`` each shard runs this
+    on its own rows (the token rows split over the batch axes) and its own
+    part of the weights: the experts split over the head axes where
+    ``expert_parallel`` shards them so, else their hidden dim, each where
+    it divides evenly. The shards' partial sums over the head axes add up
+    after."""
+    if use_pallas is None:
+        use_pallas = pallas_default()
+    mm = functools.partial(grouped_matmul, use_pallas=use_pallas,
+                           interpret=interpret)
+    shards = _SHARDS.get()
+    if shards is None:
+        return _expert_ffn_local(xf, tope, topw, w_gate, w_up, w_down, 0, mm)
+    mesh, batch_axes, head_axes = shards
+    E, _, f = w_gate.shape
+    rows = P(_split(mesh, batch_axes, xf.shape[0]), None)
+    if expert_parallel:
+        heads = _split(mesh, head_axes, E)
+        w_specs = (P(heads, None, None),) * 3
+    else:
+        heads = _split(mesh, head_axes, f)
+        w_specs = (P(None, None, heads), P(None, None, heads),
+                   P(None, heads, None))
+
+    def local(xf, tope, topw, w_gate, w_up, w_down):
+        first = (jax.lax.axis_index(heads) * w_gate.shape[0]
+                 if expert_parallel and heads else 0)
+        return _expert_ffn_local(xf, tope, topw, w_gate, w_up, w_down, first,
+                                 mm)[None]
+
+    parts = jax.shard_map(local, mesh=mesh, in_specs=(rows,) * 3 + w_specs,
+                          out_specs=P(heads, *rows), check_vma=False)(
+        xf, tope, topw, w_gate, w_up, w_down)
+    return jnp.sum(parts, axis=0)
 
 
 # ============================================================ decode attention

@@ -11,7 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, dense_init, init_rmsnorm, rmsnorm
+from repro.models.layers import (apply_rope, dense_init, init_rmsnorm,
+                                 rmsnorm, yarn_freqs, yarn_mscale)
 from repro.utils import fold_in_name
 
 NEG_INF = -1e30
@@ -108,20 +109,45 @@ def gqa_attention_block(p, x, cfg, *, positions, mode, cache=None, dispatch=None
 
 # ============================================================== MLA attention
 def init_mla(key, cfg):
+    """The query goes through a low-rank path (``wq_a``, ``q_norm``,
+    ``wq_b``; MiniCPM3) where ``q_lora_rank`` > 0, else through one plain
+    ``wq`` (DeepSeek-V2-Lite)."""
     d = cfg.d_model
     H = cfg.num_heads
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    ks = {n: fold_in_name(key, n) for n in ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")}
+    ks = {n: fold_in_name(key, n)
+          for n in ("wq", "wq_a", "wq_b", "wkv_a", "wkv_b", "wo")}
+    if qr:
+        q = {"wq_a": dense_init(ks["wq_a"], (d, qr), cfg.pdtype),
+             "q_norm": init_rmsnorm(qr, cfg.pdtype),
+             "wq_b": dense_init(ks["wq_b"], (qr, H * (nope + rope)),
+                                cfg.pdtype)}
+    else:
+        q = {"wq": dense_init(ks["wq"], (d, H * (nope + rope)), cfg.pdtype)}
     return {
-        "wq_a": dense_init(ks["wq_a"], (d, qr), cfg.pdtype),
-        "q_norm": init_rmsnorm(qr, cfg.pdtype),
-        "wq_b": dense_init(ks["wq_b"], (qr, H * (nope + rope)), cfg.pdtype),
+        **q,
         "wkv_a": dense_init(ks["wkv_a"], (d, kvr + rope), cfg.pdtype),
         "kv_norm": init_rmsnorm(kvr, cfg.pdtype),
         "wkv_b": dense_init(ks["wkv_b"], (kvr, H * (nope + vd)), cfg.pdtype),
         "wo": dense_init(ks["wo"], (H * vd, d), cfg.pdtype),
     }
+
+
+def mla_rope_and_scale(cfg):
+    """(rope frequencies [rope/2] or None for the plain ones, softmax
+    scale) of the latent attention: YaRN's blend and (qk width)^-0.5 times
+    its temperature mscale(factor, mscale_all_dim) squared where the
+    configuration scales rope (DeepSeek-V2), else the plain rope and
+    (qk width)^-0.5."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if not cfg.yarn_factor:
+        return None, scale
+    freqs = yarn_freqs(cfg.qk_rope_head_dim, cfg.rope_theta, cfg.yarn_factor,
+                       cfg.yarn_original_max_position)
+    if cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return freqs, scale
 
 
 def mla_attention_block(p, x, cfg, *, positions, mode, cache=None, dispatch=None):
@@ -138,16 +164,20 @@ def mla_attention_block(p, x, cfg, *, positions, mode, cache=None, dispatch=None
     H = cfg.num_heads
     nope, rope, vd, kvr = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                            cfg.v_head_dim, cfg.kv_lora_rank)
-    scale = (nope + rope) ** -0.5
+    freqs, scale = mla_rope_and_scale(cfg)
 
-    q = rmsnorm(p["q_norm"], x @ p["wq_a"].astype(cd)) @ p["wq_b"].astype(cd)
+    if "wq" in p:
+        q = x @ p["wq"].astype(cd)
+    else:
+        q = rmsnorm(p["q_norm"], x @ p["wq_a"].astype(cd)) @ p["wq_b"].astype(cd)
     q = q.reshape(B, S, H, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, freqs)
 
     kv_a = x @ p["wkv_a"].astype(cd)                                    # [B,S,kvr+rope]
     c_kv = rmsnorm(p["kv_norm"], kv_a[..., :kvr])                       # latent
-    k_rope = apply_rope(kv_a[..., kvr:].reshape(B, S, 1, rope), positions, cfg.rope_theta)
+    k_rope = apply_rope(kv_a[..., kvr:].reshape(B, S, 1, rope), positions,
+                        cfg.rope_theta, freqs)
 
     wkv_b = p["wkv_b"].astype(cd).reshape(kvr, H, nope + vd)
     w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]                   # [kvr,H,nope],[kvr,H,vd]
@@ -162,7 +192,7 @@ def mla_attention_block(p, x, cfg, *, positions, mode, cache=None, dispatch=None
         v_p = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad))) if pad > 0 else v
         out = kops.flash_attention(qfull, k, v_p, causal=cfg.causal,
                                    window=cfg.sliding_window,
-                                   block_kv=cfg.attn_block_kv)
+                                   block_kv=cfg.attn_block_kv, scale=scale)
         out = out[..., :vd]
         new_cache = None
         if mode == "prefill":

@@ -6,6 +6,8 @@ them. Compute runs in ``cfg.compute_dtype``; params live in
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -54,10 +56,43 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: [..., S, H, hd]; positions: [..., S] int32."""
+# the turns over the original positions where YaRN's ramp starts and ends,
+# as DeepSeek-V2 fixes them
+YARN_BETA_FAST = 32
+YARN_BETA_SLOW = 1
+
+
+def yarn_freqs(head_dim: int, theta: float, factor: float, original_max: int):
+    """YaRN's inverse frequencies as DeepSeek-V2 sets them once (a static
+    blend): the plain rope frequency for the dims that turn more than
+    ``YARN_BETA_FAST`` times over the original ``original_max`` positions,
+    that frequency over ``factor`` for the dims that turn fewer than
+    ``YARN_BETA_SLOW`` times, and a linear ramp between the two."""
+    def dim_of(turns):
+        return (head_dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(YARN_BETA_FAST)), 0)
+    high = min(math.ceil(dim_of(YARN_BETA_SLOW)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_freqs(head_dim, theta)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, 0.1 * mscale * ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def apply_rope(x, positions, theta: float, freqs=None):
+    """x: [..., S, H, hd]; positions: [..., S] int32. ``freqs`` [hd/2]
+    replaces the plain frequencies of ``theta`` (YaRN's, ``yarn_freqs``)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # [hd/2]
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # [hd/2]
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, hd/2]
     cos = jnp.cos(angles)[..., :, None, :]               # [..., S, 1, hd/2]
     sin = jnp.sin(angles)[..., :, None, :]

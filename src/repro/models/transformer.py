@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from repro.models import layers as L
 from repro.models.attention import gqa_attention_block, init_gqa, init_mla, mla_attention_block
-from repro.models.moe import init_moe, moe_apply
+from repro.models.moe import add_counts, init_moe, moe_apply
 from repro.models.ssm import init_mamba, mamba_block
 from repro.models.xlstm import init_mlstm, init_slstm, mlstm_block, slstm_block
 from repro.utils import fold_in_name
@@ -55,8 +55,10 @@ def _init_block(key, cfg, kind):
 
 
 def _apply_block(p, x, cfg, kind, *, positions, mode, cache):
+    """Returns (x, new_cache, stats): stats = {"aux": the block's auxiliary
+    loss}, and an MoE block's counters (``moe.COUNTERS``)."""
     new_cache = None
-    aux = jnp.float32(0)
+    stats = {"aux": jnp.float32(0)}
     mixer = kind["mixer"]
     h = L.rmsnorm(p["norm1"], x)
     if mixer == "attn":
@@ -76,8 +78,19 @@ def _apply_block(p, x, cfg, kind, *, positions, mode, cache):
     elif kind["ffn"] == "moe":
         y, moe_aux = moe_apply(p["moe"], L.rmsnorm(p["norm2"], x), cfg)
         x = x + y
-        aux = aux + cfg.router_aux_coef * moe_aux["lb_loss"]
-    return x, new_cache, aux
+        stats = {"aux": cfg.router_aux_coef * moe_aux["lb_loss"],
+                 "moe_held_rows": moe_aux["held_rows"],
+                 "moe_load_ratio": moe_aux["load_ratio"]}
+    return x, new_cache, stats
+
+
+def _zero_stats(cfg):
+    """The forward's stats before any block: the auxiliary loss, and the
+    MoE counters where the model has expert layers."""
+    stats = {"aux": jnp.float32(0)}
+    if cfg.moe:
+        stats.update(moe_held_rows=jnp.int32(0), moe_load_ratio=jnp.float32(0))
+    return stats
 
 
 # ------------------------------------------------------------------- model init
@@ -123,32 +136,35 @@ def _embed_inputs(params, tokens, cfg, image_embeds=None):
 # --------------------------------------------------------------------- forward
 def forward(params, tokens, cfg, *, mode, positions=None, caches=None,
             image_embeds=None):
-    """Returns (hidden [B,S',d], new_caches, aux)."""
+    """Returns (hidden [B,S',d], new_caches, stats): stats["aux"] is the
+    auxiliary loss; an MoE model's also holds ``moe_held_rows`` and
+    ``moe_load_ratio`` over its expert layers (``moe.add_counts``)."""
     kinds = cfg.layer_kinds()
     x = _embed_inputs(params, tokens, cfg, image_embeds)
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.arange(S)
 
-    aux_total = jnp.float32(0)
+    stats = _zero_stats(cfg)
     pre_caches = []
     for i, bp in enumerate(params["pre_blocks"]):
         c_in = caches["pre"][i] if caches is not None else None
-        x, c, aux = _apply_block(bp, x, cfg, {"mixer": kinds[0]["mixer"], "ffn": "dense"},
-                                 positions=positions, mode=mode, cache=c_in)
+        x, c, block = _apply_block(bp, x, cfg, {"mixer": kinds[0]["mixer"], "ffn": "dense"},
+                                   positions=positions, mode=mode, cache=c_in)
         pre_caches.append(c)
-        aux_total = aux_total + aux
+        stats = add_counts(stats, block)
 
     def period_fn(carry, scanned):
-        xc, aux_acc = carry
+        xc, acc = carry
         p_period, cache_period = scanned
         new_caches = {}
         for j, kind in enumerate(kinds):
             c_in = cache_period[f"l{j}"] if cache_period is not None else None
-            xc, c, aux = _apply_block(p_period[f"l{j}"], xc, cfg, kind,
-                                      positions=positions, mode=mode, cache=c_in)
+            xc, c, block = _apply_block(p_period[f"l{j}"], xc, cfg, kind,
+                                        positions=positions, mode=mode, cache=c_in)
             new_caches[f"l{j}"] = c
-        return (xc, aux_acc + aux), new_caches
+            acc = add_counts(acc, block)
+        return (xc, acc), new_caches
 
     if cfg.remat and mode == "train":
         if cfg.remat_policy == "save_mixer":
@@ -162,17 +178,17 @@ def forward(params, tokens, cfg, *, mode, positions=None, caches=None,
     scan_caches = caches["periods"] if caches is not None else None
     if scan_caches is None:
         # substitute a None-free placeholder: scan needs matching pytrees
-        (x, aux_total), out_caches = jax.lax.scan(
+        (x, stats), out_caches = jax.lax.scan(
             lambda c, pp: period_fn(c, (pp, _none_cache_like(kinds))),
-            (x, aux_total), params["periods"])
+            (x, stats), params["periods"])
     else:
-        (x, aux_total), out_caches = jax.lax.scan(
-            period_fn, (x, aux_total), (params["periods"], scan_caches))
+        (x, stats), out_caches = jax.lax.scan(
+            period_fn, (x, stats), (params["periods"], scan_caches))
 
     x = L.rmsnorm(params["final_norm"], x)
     new_caches = {"pre": pre_caches, "periods": out_caches} \
         if (mode != "train") else None
-    return x, new_caches, aux_total
+    return x, new_caches, stats
 
 
 def _none_cache_like(kinds):
@@ -190,12 +206,15 @@ def _unembed_last(params, hidden, cfg):
 def loss_fn(params, batch, cfg):
     """batch: tokens/labels/mask [B,S(text)] (+ image_embeds for VLM).
 
-    Returns (scalar loss, metrics dict). Image positions carry no loss.
+    Returns (scalar loss, metrics dict). Image positions carry no loss. An
+    MoE model's metrics also hold its counters over the forward:
+    ``moe_held_rows`` and ``moe_load_ratio`` (``moe.COUNTERS``).
     """
     tokens = batch["tokens"]
     image_embeds = batch.get("image_embeds")
-    hidden, _, aux = forward(params, tokens, cfg, mode="train",
-                             image_embeds=image_embeds)
+    hidden, _, stats = forward(params, tokens, cfg, mode="train",
+                               image_embeds=image_embeds)
+    aux = stats.pop("aux")
     if cfg.vlm:
         n_img = image_embeds.shape[1]
         hidden = hidden[:, n_img:]
@@ -204,7 +223,10 @@ def loss_fn(params, batch, cfg):
                                            cfg.loss_chunk)
     task_loss = s_loss / jnp.maximum(s_cnt, 1)
     loss = task_loss + aux
-    return loss, {"task_loss": task_loss, "aux_loss": aux, "tokens": s_cnt}
+    metrics = {"task_loss": task_loss, "aux_loss": aux, "tokens": s_cnt}
+    if cfg.moe:
+        metrics.update(stats)
+    return loss, metrics
 
 
 # --------------------------------------------------------------------- serving
