@@ -22,8 +22,16 @@ for it: at S=1024 causal, 3 of 4 tiles of 512 run. The running max ``m``
 and sum ``l`` are [block_q, 128] scratch, replicated across lanes, so the
 per-tile row reductions broadcast back without a lane/sublane relayout;
 the output divide and the LSE write happen once, on the row's last tile
-that runs. The backward keeps 128 tiles and reads the LSE as
-[B*KV, G, Sq] f32.
+that runs.
+
+Backward tiling (``bwd_tile_plan``): the same tiles from the lengths,
+held to blocks of at most BWD_BLOCK_ELEMS elements at the head width, and
+the same skipping in both passes. The dq pass (kv innermost) runs a q
+row's ``_kv_span``; the dk/dv pass (q innermost) runs a kv column's
+``_q_span``, and clamps the q, dO, LSE and delta blocks into it. Both read
+the LSE and delta as [B*KV, G, Sq] f32 rows: the dq pass makes them
+lane-replicated once a row, and the dk/dv pass takes its scores
+transposed, so the rows broadcast down the sublanes as they are.
 
 Forward and backward compile for TPU v5e as written
 (``tests/test_tpu_compile.py``) and match ``ref.attention_ref`` on the
@@ -42,6 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128            # the online-softmax state's lane width
 FWD_BLOCKS = (512, 256, 128)
+BWD_BLOCK_ELEMS = 512 * 1024   # the largest [tile, hd] block the backward takes
 
 
 def _fwd_block(n):
@@ -60,13 +69,46 @@ def fwd_tile_plan(Sq, Skv, *, causal=True, window=0, block_q=None,
     lengths unless given; a tile the mask empties in full is skipped."""
     block_q = _fwd_block(Sq) if block_q is None else min(block_q, Sq)
     block_kv = _fwd_block(Skv) if block_kv is None else min(block_kv, Skv)
+    return _plan(Sq, Skv, block_q, block_kv, causal=causal, window=window)
+
+
+def _bwd_block(n, hd):
+    """The backward's tile along a sequence of length n at head width hd:
+    as ``_fwd_block``, among the tiles whose [tile, hd] blocks hold at most
+    BWD_BLOCK_ELEMS elements (the dk/dv pass fits the default scoped VMEM
+    of a v5e at 512 x 1024 and 256 x 2048, not at 512 x 2048)."""
+    if n <= LANES:
+        return n
+    return next((b for b in FWD_BLOCKS
+                 if n % b == 0 and b * hd <= BWD_BLOCK_ELEMS), LANES)
+
+
+def bwd_tile_plan(Sq, Skv, hd, *, causal=True, window=0, block_q=None,
+                  block_kv=None):
+    """The backward's tiles and how many of them run, per (batch, head), for
+    the dq pass and the dk/dv pass: ((block_q, block_kv, tiles_run,
+    tiles_total), same for dk/dv). Tiles come from the lengths and the
+    head width unless given; a tile the mask empties in full is skipped."""
+    block_q = _bwd_block(Sq, hd) if block_q is None else min(block_q, Sq)
+    block_kv = _bwd_block(Skv, hd) if block_kv is None else min(block_kv, Skv)
+    mask = dict(causal=causal, window=window)
+    return (_plan(Sq, Skv, block_q, block_kv, **mask),
+            _plan(Sq, Skv, block_q, block_kv, kv_columns=True, **mask))
+
+
+def _plan(Sq, Skv, block_q, block_kv, *, causal, window, kv_columns=False):
+    """(block_q, block_kv, tiles_run, tiles_total): the tiles in the q-row
+    spans of ``_kv_span``, or with ``kv_columns`` in the kv-column spans
+    of ``_q_span``."""
     nq, nkv = Sq // block_q, Skv // block_kv
+    span, n, nother = (_q_span, nkv, nq) if kv_columns else \
+        (_kv_span, nq, nkv)
     run = 0
-    for iq in range(nq):
-        first, last = _kv_span(iq, nkv, block_q=block_q, block_kv=block_kv,
-                               causal=causal, window=window,
-                               q_offset=Skv - Sq, xp=np)
-        run += int(last) - int(first) + 1
+    for i in range(n):
+        first, last = span(i, nother, block_q=block_q, block_kv=block_kv,
+                           causal=causal, window=window, q_offset=Skv - Sq,
+                           xp=np)
+        run += max(int(last) - int(first) + 1, 0)
     return block_q, block_kv, run, nq * nkv
 
 
@@ -84,6 +126,27 @@ def _kv_span(iq, nkv, *, block_q, block_kv, causal, window, q_offset,
     return first, last
 
 
+def _q_span(ik, nq, *, block_q, block_kv, causal, window, q_offset, xp=jnp):
+    """First and last q block that sees column ``ik`` of kv tiles, the
+    mirror of ``_kv_span``: queries before the column's first key are
+    masked under ``causal``, queries ``window`` or more after its last key
+    under ``window``. ``last < first`` where no query sees the column
+    (a window with Sq < Skv)."""
+    first, last = 0, nq - 1
+    if causal:
+        first = xp.maximum(ik * block_kv - q_offset, 0) // block_q
+    if window:
+        last = xp.minimum(
+            last, ((ik + 1) * block_kv + window - 2 - q_offset) // block_q)
+    return first, last
+
+
+def _clamp(i, first, last):
+    """Grid index ``i`` held to [first, max(first, last)]: a skipped step
+    maps to a block its neighbours run, so the pipeline copies nothing."""
+    return jnp.minimum(jnp.maximum(i, first), jnp.maximum(first, last))
+
+
 def _lanes(x, n):
     """A lane-replicated [rows, 128] state as [rows, n]: whole vregs
     repeated, or lanes cut, so no row vector changes layout."""
@@ -94,12 +157,17 @@ def _lanes(x, n):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
-def _mask(block_q, block_kv, iq, ik, *, causal, window, q_offset):
+def _mask(block_q, block_kv, iq, ik, *, causal, window, q_offset,
+          kv_rows=False):
+    """The tile's [block_q, block_kv] mask, or with ``kv_rows`` the
+    [block_kv, block_q] mask of the transposed scores."""
+    shape, q_axis = ((block_kv, block_q), 1) if kv_rows else \
+        ((block_q, block_kv), 0)
     q_pos = q_offset + iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_kv), 0)
-    k_pos = ik * block_kv + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_kv), 1)
-    mask = jnp.ones((block_q, block_kv), jnp.bool_)
+        jnp.int32, shape, q_axis)
+    k_pos = ik * block_kv + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                     1 - q_axis)
+    mask = jnp.ones(shape, jnp.bool_)
     if causal:
         mask = mask & (k_pos <= q_pos)
     if window:
@@ -151,36 +219,43 @@ def _kernel_fwd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
 
 
-def _kernel_dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *,
-               causal, window, scale, block_q, block_kv, nkv, q_offset):
+def _kernel_dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+               acc_ref, lse_sc, delta_sc, *, causal, window, scale, block_q,
+               block_kv, nkv, q_offset):
     """dq = sum_kv (P o (dP - delta)) K * scale, P = exp(S - LSE).
-    Grid: (BKV, G, nq, nkv); kv innermost, accumulated in VMEM scratch."""
+    Grid: (BKV, G, nq, nkv); kv innermost, accumulated in VMEM scratch over
+    the row's kv tiles that the mask leaves a key in. The row's LSE and
+    delta are made lane-replicated once, on its first tile."""
     iq = pl.program_id(2)
     ik = pl.program_id(3)
+    first, last = _kv_span(iq, nkv, block_q=block_q, block_kv=block_kv,
+                           causal=causal, window=window, q_offset=q_offset)
 
-    @pl.when(ik == 0)
+    @pl.when(ik == first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        lse_sc[...] = jnp.broadcast_to(lse_ref[0, 0][:, None], lse_sc.shape)
+        delta_sc[...] = jnp.broadcast_to(delta_ref[0, 0][:, None],
+                                         delta_sc.shape)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    @pl.when((ik >= first) & (ik <= last))
+    def _step():
+        q = q_ref[0, 0].astype(jnp.float32) * scale            # [bq, hd]
+        k = k_ref[0].astype(jnp.float32)                       # [bk, hd]
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0, 0].astype(jnp.float32)                  # [bq, hd]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # [bq, bk]
+        mask = _mask(block_q, block_kv, iq, ik, causal=causal, window=window,
+                     q_offset=q_offset)
+        p = jnp.where(mask, jnp.exp(s - _lanes(lse_sc[...], block_kv)), 0.0)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - _lanes(delta_sc[...], block_kv))
+        acc_ref[...] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    mask = _mask(block_q, block_kv, iq, ik, causal=causal, window=window,
-                 q_offset=q_offset)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
-    acc_ref[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    @pl.when(ik == nkv - 1)
+    @pl.when(ik == last)
     def _done():
         dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
@@ -188,37 +263,42 @@ def _kernel_dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
 def _kernel_dkv(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *,
                 causal, window, scale, block_q, block_kv, nq, q_offset):
-    """dk/dv for one kv block; grid (BKV, G, nkv, nq) with q innermost.
-    dv = P^T dO ; dk = dS^T Q * scale."""
+    """dk/dv for one kv tile; grid (BKV, G, nkv, nq) with q innermost, over
+    the q tiles that see the kv tile. Scores are taken transposed,
+    S^T = K Q^T [bk, bq], so the LSE and delta rows broadcast down the
+    sublanes and every product is plain or against a transposed right
+    operand: dv = P^T dO ; dk = dS^T Q * scale. A kv tile that no query
+    sees writes zeros."""
     ik = pl.program_id(2)
     iq = pl.program_id(3)
+    first, last = _q_span(ik, nq, block_q=block_q, block_kv=block_kv,
+                          causal=causal, window=window, q_offset=q_offset)
 
-    @pl.when(iq == 0)
+    @pl.when(iq == first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    @pl.when((iq >= first) & (iq <= last))
+    def _step():
+        q = q_ref[0, 0].astype(jnp.float32) * scale            # [bq, hd]
+        k = k_ref[0].astype(jnp.float32)                       # [bk, hd]
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0, 0].astype(jnp.float32)                  # [bq, hd]
+        st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)  # [bk, bq]
+        mask = _mask(block_q, block_kv, iq, ik, causal=causal, window=window,
+                     q_offset=q_offset, kv_rows=True)
+        pt = jnp.where(mask, jnp.exp(st - lse_ref[0]), 0.0)   # lse [1, bq]
+        dv_acc[...] += jax.lax.dot_general(
+            pt, do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0])
+        dk_acc[...] += jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    mask = _mask(block_q, block_kv, iq, ik, causal=causal, window=window,
-                 q_offset=q_offset)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)          # [bq, bk]
-    dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
-    dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-
-    @pl.when(iq == nq - 1)
+    @pl.when(iq == jnp.maximum(first, last))
     def _done():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -293,65 +373,86 @@ def flash_attention_fwd_pallas(q, k, v, *, causal=True, window=0, scale=None,
 
 
 def flash_attention_bwd_pallas(q, k, v, out, lse, do, *, causal=True, window=0,
-                               scale=None, block_q=128, block_kv=128,
+                               scale=None, block_q=None, block_kv=None,
                                interpret=False):
-    """Two-pass flash backward: (dq, dk, dv), all like their primals."""
+    """Two-pass flash backward: (dq, dk, dv), all like their primals. Tiles
+    from ``bwd_tile_plan`` unless given; each pass skips the tiles the mask
+    empties, and its skipped steps map to blocks it runs, so they copy
+    nothing."""
     qr, kr, vr, dims = _layout(q, k, v)
     B, Sq, H, hd, Skv, KV, G = dims
     or_, dor = (_layout(out, k, v)[0], _layout(do, k, v)[0])
     if scale is None:
         scale = hd ** -0.5
-    block_q = min(block_q, Sq)
-    block_kv = min(block_kv, Skv)
-    nq, nkv = Sq // block_q, Skv // block_kv
-    q_offset = Skv - Sq
+    dq_plan, dkv_plan = bwd_tile_plan(Sq, Skv, hd, causal=causal,
+                                      window=window, block_q=block_q,
+                                      block_kv=block_kv)
+    mask = dict(causal=causal, window=window, q_offset=Skv - Sq)
 
     # delta = rowsum(dO o O) — tiny, compute with jnp
     delta = jnp.sum(dor.astype(jnp.float32) * or_.astype(jnp.float32), axis=-1)
 
-    common = dict(causal=causal, window=window, scale=scale,
-                  block_q=block_q, block_kv=block_kv, q_offset=q_offset)
+    # dq: q block outer, kv block inner (sequential) so dq accumulates
+    bq, bk = dq_plan[:2]
+    nq, nkv = Sq // bq, Skv // bk
+    tiles = dict(block_q=bq, block_kv=bk, **mask)
+    kv_span = functools.partial(_kv_span, nkv=nkv, **tiles)
 
-    q_spec = pl.BlockSpec((1, 1, block_q, hd), lambda b, g, i, j: (b, g, i, 0))
-    kv_spec_q = pl.BlockSpec((1, block_kv, hd), lambda b, g, i, j: (b, j, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, g, i, j: (b, g, i))
+    def kv_map(b, g, iq, ik):
+        return (b, _clamp(ik, *kv_span(iq)), 0)
+
+    q_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, g, iq, ik: (b, g, iq, 0))
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, g, iq, ik: (b, g, iq))
+    kv_spec = pl.BlockSpec((1, bk, hd), kv_map)
 
     with jax.named_scope("kernel.flash_bwd_dq"):
         dq = pl.pallas_call(
-            functools.partial(_kernel_dq, nkv=nkv, **common),
+            functools.partial(_kernel_dq, scale=scale, nkv=nkv, **tiles),
             name="kernel.flash_bwd_dq",
             grid=(B * KV, G, nq, nkv),
-            in_specs=[q_spec, kv_spec_q, kv_spec_q, q_spec, row_spec,
-                      row_spec],
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct((B * KV, G, Sq, hd), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32),
+                            pltpu.VMEM((bq, LANES), jnp.float32),
+                            pltpu.VMEM((bq, LANES), jnp.float32)],
             interpret=interpret,
         )(qr, kr, vr, dor, lse, delta)
 
     # dk/dv: kv block outer, q block inner (sequential) so dk/dv accumulate
-    q_spec2 = pl.BlockSpec((1, 1, block_q, hd), lambda b, g, j, i: (b, g, i, 0))
-    kv_spec2 = pl.BlockSpec((1, block_kv, hd), lambda b, g, j, i: (b, j, 0))
-    row_spec2 = pl.BlockSpec((1, 1, block_q), lambda b, g, j, i: (b, g, i))
+    bq, bk = dkv_plan[:2]
+    nq, nkv = Sq // bq, Skv // bk
+    tiles = dict(block_q=bq, block_kv=bk, **mask)
+    q_span = functools.partial(_q_span, nq=nq, **tiles)
 
-    # dk/dv: the out block (b, j) is revisited once per q-head group g with
-    # other j blocks in between, so cross-g accumulation can't live in VMEM
+    def q_map(b, g, ik, iq):
+        return (b, g, _clamp(iq, *q_span(ik)), 0)
+
+    def row_map(b, g, ik, iq):
+        return (b, g, _clamp(iq, *q_span(ik)))
+
+    q_spec = pl.BlockSpec((1, 1, bq, hd), q_map)
+    row_spec = pl.BlockSpec((1, 1, bq), row_map)
+    kv_spec = pl.BlockSpec((1, bk, hd), lambda b, g, ik, iq: (b, ik, 0))
+
+    # the out block (b, ik) is revisited once per q-head group g with other
+    # ik blocks in between, so cross-g accumulation can't live in VMEM
     # scratch — run one call per group and sum (G is small: <= 8 for the
     # assigned archs). G==1 (MHA after grouping) needs a single call.
     def _dkv_call(qg, dog, lseg, deltag):
         with jax.named_scope("kernel.flash_bwd_dkv"):
             return pl.pallas_call(
-                functools.partial(_kernel_dkv, nq=nq, **common),
+                functools.partial(_kernel_dkv, scale=scale, nq=nq, **tiles),
                 name="kernel.flash_bwd_dkv",
                 grid=(B * KV, 1, nkv, nq),
-                in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2,
-                          row_spec2],
-                out_specs=[kv_spec2, kv_spec2],
+                in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec,
+                          row_spec],
+                out_specs=[kv_spec, kv_spec],
                 out_shape=[
                     jax.ShapeDtypeStruct((B * KV, Skv, hd), jnp.float32),
                     jax.ShapeDtypeStruct((B * KV, Skv, hd), jnp.float32)],
-                scratch_shapes=[pltpu.VMEM((block_kv, hd), jnp.float32),
-                                pltpu.VMEM((block_kv, hd), jnp.float32)],
+                scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
+                                pltpu.VMEM((bk, hd), jnp.float32)],
                 interpret=interpret,
             )(qg, kr, vr, dog, lseg, deltag)
 
@@ -376,7 +477,8 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, kv_len=None,
 
     Differentiable: forward saves per-row LSE; backward runs the two-pass
     flash backward kernels (dq then dk/dv). Given tiles serve both passes;
-    by default the forward takes ``fwd_tile_plan``'s and the backward 128."""
+    by default the forward takes ``fwd_tile_plan``'s and the backward
+    ``bwd_tile_plan``'s."""
     assert kv_len is None, "flash path assumes a full kv sequence"
     fwd = functools.partial(
         flash_attention_fwd_pallas, causal=causal, window=window, scale=scale,
@@ -394,8 +496,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, kv_len=None,
         q, k, v, out, lse = res
         return flash_attention_bwd_pallas(
             q, k, v, out, lse, do, causal=causal, window=window, scale=scale,
-            block_q=block_q or 128, block_kv=block_kv or 128,
-            interpret=interpret)
+            block_q=block_q, block_kv=block_kv, interpret=interpret)
 
     _fa.defvjp(_fwd, _bwd)
     return _fa(q, k, v)

@@ -83,12 +83,12 @@ def test_fwd_lse_at_large_tiles(Sq, Skv, window, tile):
 
 
 @pytest.mark.parametrize("S,H,KV,window", [
-    (1024, 2, 1, 0),            # forward at 512 tiles, backward at 128
+    (1024, 2, 1, 0),            # both at 512 tiles, 3 of 4 run
     (1024, 2, 2, 300),
 ])
 def test_flash_backward_after_planned_forward(S, H, KV, window):
-    """Default tiles: the forward takes the plan's (512), the backward its
-    128, and reads the forward's LSE."""
+    """Default tiles: the forward and the backward take their plans' (512
+    here), and the backward reads the forward's LSE."""
     key = jax.random.PRNGKey(3)
     q = jax.random.normal(key, (1, S, H, 32))
     k = jax.random.normal(jax.random.fold_in(key, 1), (1, S, KV, 32))
@@ -107,3 +107,43 @@ def test_flash_backward_after_planned_forward(S, H, KV, window):
     for a, b, name in zip(gp, gr, ("dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("Sq,Skv,H,KV,causal,window,tile", [
+    (1024, 1024, 2, 2, True, 0, None),      # 3 of 4 tiles run, each pass
+    (1024, 1024, 2, 2, True, 300, None),    # window
+    (512, 1024, 2, 2, True, 300, None),     # keys no query sees
+    (512, 1024, 2, 2, True, 300, 128),      # a kv tile no query sees
+    (1024, 1024, 2, 2, False, 0, None),     # nothing skipped
+    (1024, 1024, 4, 2, True, 0, None),      # GQA, G = 2
+])
+def test_flash_backward_at_planned_tiles(Sq, Skv, H, KV, causal, window,
+                                         tile):
+    """The backward at ``bwd_tile_plan``'s tiles (or the given ones),
+    skipping the tiles the mask empties in both passes, against autodiff
+    of the oracle; a key that no query sees gets exactly zero dk and dv."""
+    key = jax.random.PRNGKey(4)
+    q = jax.random.normal(key, (1, Sq, H, 32))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, Skv, KV, 32))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, Skv, KV, 32))
+
+    def loss_pal(q, k, v):
+        o = flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                   block_q=tile, block_kv=tile,
+                                   interpret=True)
+        return jnp.sum(o ** 2)
+
+    def loss_ref(q, k, v):
+        o = ref.attention_ref(q, k, v, causal=causal, window=window)
+        return jnp.sum(o ** 2)
+
+    gp = jax.grad(loss_pal, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gp, gr, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-4, err_msg=name)
+    if window and Sq < Skv:
+        # the first query (Skv - Sq) sees keys above Skv - Sq - window only
+        unseen = Skv - Sq - window + 1
+        for grad in gp[1:]:
+            assert not np.asarray(grad[:, :unseen]).any()

@@ -8,7 +8,8 @@ import pytest
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.fedagg import fedagg_pallas
-from repro.kernels.flash_attention import (flash_attention_fwd_pallas,
+from repro.kernels.flash_attention import (bwd_tile_plan,
+                                           flash_attention_fwd_pallas,
                                            flash_attention_pallas,
                                            fwd_tile_plan)
 from repro.kernels.rmsnorm import rmsnorm_pallas
@@ -116,6 +117,33 @@ def test_fwd_tile_plan(Sq, Skv, kw, want):
     bq, bk, run, _ = plan
     assert run == _tiles_with_a_key(Sq, Skv, bq, bk, kw.get("causal", True),
                                     kw.get("window", 0))
+
+
+@pytest.mark.parametrize("Sq,Skv,hd,kw,want", [
+    (1024, 1024, 96, dict(window=2047), (512, 512, 3, 4)),   # Phi-3 silo
+    (512, 512, 64, {}, (512, 512, 1, 1)),                    # Qwen xdevice
+    (4096, 4096, 192, {}, (512, 512, 36, 64)),               # DeepSeek silo8k
+    (1024, 1024, 64, dict(causal=False), (512, 512, 4, 4)),
+    (1024, 1024, 64, dict(window=300, block_q=128, block_kv=128),
+     (128, 128, 26, 64)),
+    (512, 1024, 64, dict(window=300), (512, 512, 2, 2)),
+    (512, 1024, 64, dict(window=300, block_q=128, block_kv=128),
+     (128, 128, 16, 32)),                                    # kv tile 0 unseen
+    (256, 1024, 64, dict(causal=False, window=200), (256, 512, 1, 2)),
+    (1024, 1024, 64, dict(block_q=64, block_kv=128), (64, 128, 72, 128)),
+    (1024, 1024, 2048, {}, (256, 256, 10, 16)),              # wide heads
+])
+def test_bwd_tile_plan(Sq, Skv, hd, kw, want):
+    """Tiles from the lengths and head width (explicit ones honoured); in
+    each pass the tiles that run are exactly those the mask leaves a key
+    in."""
+    dq_plan, dkv_plan = bwd_tile_plan(Sq, Skv, hd, **kw)
+    assert dq_plan == want
+    for bq, bk, run, total in (dq_plan, dkv_plan):
+        assert total == (Sq // bq) * (Skv // bk)
+        assert run == _tiles_with_a_key(Sq, Skv, bq, bk,
+                                        kw.get("causal", True),
+                                        kw.get("window", 0))
 
 
 # ------------------------------------------------------------ decode attention

@@ -3,13 +3,14 @@
 Each test compiles for a described (not attached) TPU v5e: ``fedagg`` at
 C = 4 clients x M = Qwen1.5-0.5B's parameter count, flash attention
 forward and backward at B=4, S=512, H=KV=16, hd=64, at Phi-3-mini's
-(2, 1024, 32, 96) with its 2047-token window, where the forward takes
-512 tiles and skips the ones above the diagonal, and at DeepSeek-V2-Lite's
-latent attention (1, 4096, 16, 192); the grouped expert matmul forward
-and backward at that model's expert shapes; and that model's whole
-temporal round at the shapes of its benchmark cell, which must fit the
-chip's memory; and an expert-parallel round of it on the 2x2 mesh, where
-each chip computes its own experts' products. Mosaic refuses what
+(2, 1024, 32, 96) with its 2047-token window, and at DeepSeek-V2-Lite's
+latent attention (1, 4096, 16, 192), where the forward takes
+``fwd_tile_plan``'s tiles and the backward ``bwd_tile_plan``'s (512 at
+each) and both skip the ones above the diagonal; the grouped expert
+matmul forward and backward at that model's expert shapes; and that
+model's whole temporal round at the shapes of its benchmark cell, which
+must fit the chip's memory; and an expert-parallel round of it on the 2x2
+mesh, where each chip computes its own experts' products. Mosaic refuses what
 interpret mode accepts (1-D blocks, in-kernel gathers, unaligned tiles,
 too much VMEM), and the compiler refuses a program that does not fit the
 chip's HBM, so these guard every change to the kernels without a chip.
